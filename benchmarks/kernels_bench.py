@@ -1,28 +1,19 @@
-"""Pallas-kernel microbenchmarks (interpret mode: correctness + shape sweep
-timings; real TPU numbers come from running the same entry points with
-``interpret=False``)."""
+"""Pallas-kernel correctness sweeps against the ``ref.py`` oracles.
+
+These sweeps check values only and time nothing: on the host the kernels
+run under the Pallas interpreter (``interpret=True``), whose speed says
+nothing about the compiled kernel, and the D=64 multi-head attention shapes
+are ones Mosaic does not compile.  Kernel times come from a profiler trace
+on the chip, not from here."""
 
 from __future__ import annotations
 
-import time
 from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import ops, ref
-
-
-def _time(fn, *args, reps=3, **kw):
-    fn(*args, **kw)  # warmup/compile
-    ts = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        jax.block_until_ready(out)
-        ts.append(time.perf_counter() - t0)
-    return float(np.median(ts)), out
 
 
 def gemv_sweep() -> Dict:
@@ -31,9 +22,9 @@ def gemv_sweep() -> Dict:
     for (M, K) in ((256, 2048), (512, 8192), (1024, 8192)):
         a = jax.random.normal(rng, (M, K), jnp.float32)
         x = jax.random.normal(rng, (K, 1), jnp.float32)
-        t, y = _time(ops.gemv, a, x, bm=128, bk=512)
+        y = ops.gemv(a, x, bm=128, bk=512, interpret=True)
         err = float(jnp.max(jnp.abs(y - ref.gemv_ref(a, x))))
-        rows.append({"M": M, "K": K, "us": t * 1e6, "max_err": err})
+        rows.append({"M": M, "K": K, "max_err": err})
     return {"rows": rows, "pass": all(r["max_err"] < 1e-3 for r in rows)}
 
 
@@ -44,9 +35,10 @@ def decode_attention_sweep() -> Dict:
         q = jax.random.normal(rng, (B, H, D), jnp.float32)
         k = jax.random.normal(rng, (B, S, KV, D), jnp.float32)
         v = jax.random.normal(rng, (B, S, KV, D), jnp.float32)
-        t, o = _time(ops.decode_attention, q, k, v, jnp.int32(S - 3), bs=256)
+        o = ops.decode_attention(q, k, v, jnp.int32(S - 3), bs=256,
+                                 interpret=True)
         err = float(jnp.max(jnp.abs(o - ref.decode_attention_ref(q, k, v, S - 3))))
-        rows.append({"B": B, "H": H, "S": S, "us": t * 1e6, "max_err": err})
+        rows.append({"B": B, "H": H, "S": S, "max_err": err})
     return {"rows": rows, "pass": all(r["max_err"] < 1e-3 for r in rows)}
 
 

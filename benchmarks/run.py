@@ -76,7 +76,7 @@ def main() -> None:
     )
 
     print("-" * 72)
-    print("Pallas kernel micro-benchmarks (interpret mode)")
+    print("Pallas kernel correctness vs. ref.py (interpret mode; no timings)")
     for name, out in kernels_bench.all_benches().items():
         results[f"kernel_{name}"] = out
         print(f"[bench] kernel_{name:17s} "

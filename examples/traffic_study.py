@@ -8,12 +8,16 @@ This is the paper's Fig. 4 workflow end-to-end inside one process:
  (3) analysis: replay under spin vs. SyncMon synchronization and under
      perturbed (straggler) peers, and compare exposure.
 
+A CPU placeholder tool: the 4x4 mesh is 16 virtual host devices, on every
+machine, so the captured HLO is the CPU backend's partitioned program.
+
     PYTHONPATH=src python examples/traffic_study.py
 """
 
 import os
 import sys
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=16")
 sys.path.insert(0, "src")
 
@@ -31,6 +35,7 @@ from repro.core import (  # noqa: E402
 from repro.core.hlo_capture import parse_collectives, schedule_to_trace, summarize  # noqa: E402
 from repro.core.predictor import predict_step, roofline  # noqa: E402
 from repro.core.topology import Topology  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import Model  # noqa: E402
 from repro.training import TrainConfig, build_train_step  # noqa: E402
 from repro.optim import AdamWConfig, adamw_init  # noqa: E402
@@ -39,7 +44,7 @@ from repro.optim import AdamWConfig, adamw_init  # noqa: E402
 def main() -> None:
     # (1) capture: compile a sharded train step for a reduced gemma3-1b
     cfg = reduced(get_config("gemma3-1b")).with_(n_layers=4)
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     model = Model(cfg, mesh=mesh)
     step_fn, shardings, _ = build_train_step(
         model, mesh, TrainConfig(optim=AdamWConfig())

@@ -19,6 +19,7 @@ import jax  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.data import DataConfig, SyntheticLMDataset, prefetch  # noqa: E402
 from repro.ft import SimulatedFailure  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import Model  # noqa: E402
 from repro.optim import AdamWConfig  # noqa: E402
 from repro.training import TrainConfig, Trainer  # noqa: E402
@@ -38,7 +39,7 @@ def main() -> None:
     model = Model(cfg)
     print(f"[e2e] {cfg.name}: {model.n_params()/1e6:.1f}M params")
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     data = SyntheticLMDataset(
         DataConfig(vocab=cfg.vocab, seq_len=args.seq, global_batch=args.batch)
     )

@@ -44,9 +44,10 @@ fallback into a hard error.
 
 ``replay_lane_numpy``/``replay_lane_jax`` expose the same closed form as a
 standalone whole-lane replay over dense step arrays (``lane_step_arrays``) —
-the numpy reference and the ``jax.lax.scan`` variant for accelerator-resident
-fabric sweeps — validated against each other and against real cluster runs in
-``tests/test_timeline.py``.
+the int64 numpy reference and the jittable int32 ``jax.lax.scan`` variant,
+whose inputs ``lane_int32`` range-checks at the host boundary — validated
+against each other and against real cluster runs in
+``tests/test_timeline.py``, and run on the TPU by ``chip_smoke.py``.
 """
 
 from __future__ import annotations
@@ -66,6 +67,7 @@ __all__ = [
     "timeline_support",
     "lane_step_arrays",
     "replay_lane_numpy",
+    "lane_int32",
     "replay_lane_jax",
 ]
 
@@ -637,30 +639,67 @@ def replay_lane_numpy(
     return reads, t
 
 
+def lane_int32(dispatch, is_wait, val, *, poll: int, check: int):
+    """Host boundary of :func:`replay_lane_jax`: int32 copies of the inputs.
+
+    Every cycle the replay computes is at most ``max(dispatch, max wait
+    value + poll) + sum(timed durations) + n_waits * check`` (by induction
+    over steps: a wait ends at most ``poll - 1 + check`` past its flag or
+    ``check`` past its entry, a timed step adds its duration), and each
+    member's read count is at most that bound over ``poll`` plus one per
+    wait.  The bound is evaluated in Python integers; past ``2**31 - 1``
+    this raises ``OverflowError`` instead of letting the int32 device
+    arithmetic wrap.  Negative cycles or durations raise ``ValueError``.
+    """
+    dispatch = np.asarray(dispatch, np.int64)
+    is_wait = np.asarray(is_wait, bool)
+    val = np.asarray(val, np.int64)
+    if (dispatch < 0).any() or (val < 0).any():
+        raise ValueError("lane replay takes non-negative cycles and durations")
+    waits = val[is_wait]
+    start = max(
+        int(dispatch.max(initial=0)),
+        int(waits.max(initial=0)) + poll,
+    )
+    bound = start + int(val[~is_wait].sum()) + len(waits) * check
+    limit = int(np.iinfo(np.int32).max)
+    if max(bound, bound // poll + len(waits)) > limit:
+        raise OverflowError(
+            f"lane replay may reach cycle {bound:,}, past int32's {limit:,}"
+        )
+    return dispatch.astype(np.int32), is_wait, val.astype(np.int32)
+
+
 def replay_lane_jax(dispatch, is_wait, val, *, poll: int, check: int):
     """The same closed form as a branchless ``jax.lax.scan`` over steps.
 
-    Integer arithmetic throughout (int32 under jax's default x64-disabled
-    config — fine for the cycle ranges of a lane replay; the numpy reference
-    is the int64 ground truth).  Returns
+    Pure ``jax.numpy``, so it jits over device arrays (``poll`` and
+    ``check`` are static).  Arithmetic is int32 (jax's default, native on
+    the TPU): ``dispatch`` and ``val`` must be int32 arrays, which
+    :func:`lane_int32` makes after proving that no value can wrap; other
+    dtypes raise ``TypeError``.  Returns
     ``(flag_reads_per_cohort_member, end_cycle_per_cohort)`` as jax arrays.
     """
     import jax
     import jax.numpy as jnp
 
-    xs = (
-        jnp.asarray(np.asarray(is_wait, bool)),
-        jnp.asarray(np.asarray(val, np.int32)),
-    )
+    for name, arr in (("dispatch", dispatch), ("val", val)):
+        if arr.dtype != jnp.int32:
+            raise TypeError(
+                f"replay_lane_jax needs int32 {name} (got {arr.dtype}); "
+                "convert with lane_int32, which checks the range"
+            )
 
-    def step(t, x):
+    def step(carry, x):
+        t, reads = carry
         w, v = x
-        nticks = jnp.maximum((v - t + poll - 1) // poll, 0)
-        t_wait = t + nticks * poll + check
-        t_timed = t + v
-        return jnp.where(w, t_wait, t_timed), jnp.where(w, nticks + 1, 0)
+        # the timed branch's v is zeroed out of the wait arithmetic and
+        # vice versa, so every intermediate stays under lane_int32's bound
+        nticks = jnp.where(w, jnp.maximum((v - t + poll - 1) // poll, 0), 0)
+        t = t + nticks * poll + jnp.where(w, check, v)
+        return (t, reads + jnp.where(w, nticks + 1, 0)), None
 
-    t, per_step_reads = jax.lax.scan(
-        step, jnp.asarray(np.asarray(dispatch, np.int32)), xs
+    (t, reads), _ = jax.lax.scan(
+        step, (dispatch, jnp.zeros_like(dispatch)), (is_wait, val)
     )
-    return per_step_reads.sum(axis=0), t
+    return reads, t

@@ -26,6 +26,8 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+from .hlo_capture import group_size
+
 __all__ = ["HloModule", "analyze_hlo", "OpCost"]
 
 _DTYPE_BYTES = {
@@ -46,8 +48,6 @@ _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
 _CONSTANT_VAL = re.compile(r"constant\((\-?\d+)\)")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _CONTRACT = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
-_IOTA_RG = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=\[")
-_BRACE_RG = re.compile(r"replica_groups=\{\{([0-9, ]+)\}")
 
 _COLLECTIVES = {
     "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -392,16 +392,17 @@ def analyze_hlo(text: str) -> HloModule:
             op.flops = float(relems)
         base = opcode[:-6] if opcode.endswith("-start") else opcode
         if base in _COLLECTIVES and not opcode.endswith("-done"):
-            gsz = 1
-            gm = _IOTA_RG.search(rest)
-            if gm:
-                gsz = int(gm.group(2))
-            else:
-                bm2 = _BRACE_RG.search(rest)
-                if bm2:
-                    gsz = len([x for x in bm2.group(1).split(",") if x.strip()])
+            gsz = group_size(rest)
             op.collective_kind = base
             op.group_size = gsz
+            if opcode.endswith("-start"):
+                # async start: a tuple of (operand, result, context) buffers;
+                # the result is the largest of them, not their sum
+                rbytes = max(
+                    (_type_bytes_elems(m.group(0))[0]
+                     for m in _SHAPE.finditer(type_str)),
+                    default=0,
+                )
             if base == "all-gather":
                 op.collective_bytes = rbytes // max(gsz, 1)
             elif base == "reduce-scatter":

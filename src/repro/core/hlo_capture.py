@@ -54,9 +54,13 @@ _SHAPE_RE = re.compile(r"\b([a-z]+[0-9]*(?:e[0-9a-z]+)?)\[([0-9,]*)\]")
 # e.g.  replica_groups=[2,4]<=[8]   replica_groups={{0,1},{2,3}}
 _IOTA_RG_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=\[")
 _BRACE_RG_RE = re.compile(r"replica_groups=\{\{([0-9, ]+)\}")
+# collective-permute names its peers instead of groups:
+#   source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
+_PAIRS_RE = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)+)\}")
 # HLO op line:  %name = TYPE kind(...)  or  %name = (T1, T2) kind-start(...)
+# TYPE may carry TPU layouts, e.g. f32[8,2048]{1,0:T(8,128)S(1)}
 _HLO_OP_RE = re.compile(
-    r"=\s+(\(?[a-z0-9\[\]{},() ]+?\)?)\s+"
+    r"=\s+(\(?[^=]+?\)?)\s+"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\("
 )
@@ -115,14 +119,7 @@ def parse_collectives(text: str) -> List[CollectiveOp]:
             if line.find(f"{kind}-done") != -1 and not is_start:
                 continue  # -done carries no new traffic
             rbytes, dtype = _first_tensor_bytes(m.group(1))
-            gsize = 1
-            gm = _IOTA_RG_RE.search(line)
-            if gm:
-                gsize = int(gm.group(2))
-            else:
-                bm = _BRACE_RG_RE.search(line)
-                if bm:
-                    gsize = len([x for x in bm.group(1).split(",") if x.strip()])
+            gsize = group_size(line)
             ops.append(
                 CollectiveOp(
                     kind=kind,
@@ -158,6 +155,21 @@ def parse_collectives(text: str) -> List[CollectiveOp]:
                 )
             )
     return ops
+
+
+def group_size(line: str) -> int:
+    """Participants per replica group of one HLO collective (1 if unknown);
+    for collective-permute, the devices its source/target pairs touch."""
+    gm = _IOTA_RG_RE.search(line)
+    if gm:
+        return int(gm.group(2))
+    bm = _BRACE_RG_RE.search(line)
+    if bm:
+        return len([x for x in bm.group(1).split(",") if x.strip()])
+    pm = _PAIRS_RE.search(line)
+    if pm:
+        return len(set(re.findall(r"\d+", pm.group(1))))
+    return 1
 
 
 def _operand_bytes(kind: str, result_bytes: int, group_size: int) -> int:

@@ -9,10 +9,6 @@ dense array passes over all workgroups at once.  Results are bit-identical to
 the cycle/event engines (asserted in tests); wall time is near-constant in
 simulated cycles and sub-linear in everything else.
 
-A jax.lax.scan variant of the spin-read closed form is provided for the
-pod-scale replay path (``repro.core.predictor``), demonstrating the engine
-itself can run on the accelerator.
-
 This engine is replay-only and gemv-specific; the same closed forms applied
 to the N-device closed loop live in ``repro.core.cohort_timeline`` (lanes)
 and ``repro.core.lockstep`` (all ranks × all loop steps of a symbolic
@@ -264,32 +260,3 @@ def run_vectorized(sim) -> "Report":  # noqa: F821 - avoids circular import
         per_device={0: dict(traffic)},
         closed_loop=False,
     )
-
-
-# ---------------------------------------------------------------------------
-# jax.lax.scan variant of the spin-wait closed form (accelerator-residency
-# demonstration; used by the pod-scale predictor)
-# ---------------------------------------------------------------------------
-
-
-def spin_reads_jax(wait_start, flag_T, poll: int, check: int):
-    """flag reads + wait-end cursor for SPIN mode, as a jax scan over flags.
-
-    wait_start: f32[nwg] wait-phase entry cycles
-    flag_T:     f32[npeers] flag visibility cycles (polling order)
-    returns (reads_per_wg, cursor_after) — matches the numpy closed form.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    def step(c, T):
-        already = T <= c
-        nticks = jnp.where(
-            already, 0, jnp.ceil(jnp.maximum(T - c, 0) / poll)
-        ).astype(jnp.int32)
-        reads = jnp.where(already, 1, nticks + 1)
-        c2 = jnp.where(already, c + check, c + nticks * poll + check)
-        return c2, reads
-
-    cursor, reads = jax.lax.scan(step, wait_start.astype(jnp.float32), flag_T)
-    return reads.sum(axis=0), cursor

@@ -43,14 +43,17 @@ class SyntheticLMDataset:
             raise ValueError("global_batch must divide evenly across hosts")
         self.cfg = cfg
         rng = np.random.default_rng(cfg.seed)
-        # sparse-ish transition structure => learnable bigram statistics
-        logits = rng.normal(0.0, 2.0, size=(cfg.vocab, cfg.vocab))
-        keep = rng.random((cfg.vocab, cfg.vocab)) < (16.0 / cfg.vocab)
-        logits = np.where(keep, logits, -1e9)
-        logits[:, 1 % cfg.vocab] = 0.0  # guarantee an escape transition
+        # sparse transition structure => learnable bigram statistics: each
+        # token has 16 random successors plus an escape to token 1, kept as
+        # [vocab, 17] tables (a dense [vocab, vocab] matrix would be 550 GB
+        # at a 262k vocabulary)
+        V = cfg.vocab
+        succ = rng.integers(0, V, size=(V, 16))
+        logits = rng.normal(0.0, 2.0, size=(V, 16))
+        self._succ = np.concatenate([succ, np.full((V, 1), 1 % V)], axis=1)
+        logits = np.concatenate([logits, np.zeros((V, 1))], axis=1)
         p = np.exp(logits - logits.max(axis=1, keepdims=True))
-        self._P = p / p.sum(axis=1, keepdims=True)
-        self._cumP = np.cumsum(self._P, axis=1)
+        self._cumP = np.cumsum(p / p.sum(axis=1, keepdims=True), axis=1)
 
     @property
     def host_batch(self) -> int:
@@ -68,7 +71,8 @@ class SyntheticLMDataset:
         doc_left = rng.geometric(1.0 / cfg.mean_doc_len, size=B)
         for t in range(S + 1):
             u = rng.random(B)
-            state = (self._cumP[state] > u[:, None]).argmax(axis=1)
+            pick = (self._cumP[state] > u[:, None]).argmax(axis=1)
+            state = self._succ[state, pick]
             end = doc_left <= 0
             if end.any():
                 state = np.where(end, cfg.separator_token, state)

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import SHARD_MAP_NO_CHECK, axis_size, pvary, shard_map
 
 __all__ = [
     "psum_matmul",
@@ -43,12 +42,12 @@ def psum_matmul(mesh: Mesh, axis: str = "model"):
         y_part = x @ w
         return jax.lax.psum(y_part, axis)
 
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
         out_specs=P(None, None),
-        **SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
 
 
@@ -67,7 +66,7 @@ def fused_gemv_allreduce(mesh: Mesh, axis: str = "model"):
     Numerically identical to ``psum_matmul`` (tested).
     """
     def inner(x, w):
-        n_dev = axis_size(axis)
+        n_dev = jax.lax.axis_size(axis)
         idx = jax.lax.axis_index(axis)
         B = x.shape[0]
 
@@ -93,7 +92,7 @@ def fused_gemv_allreduce(mesh: Mesh, axis: str = "model"):
             recv = jax.lax.ppermute(buf, axis, perm)
             return (recv, yt_local), None
 
-        zero = pvary(jnp.zeros((B, tile), y.dtype), (axis,))
+        zero = jnp.zeros((B, tile), y.dtype)
         (acc, _), _ = jax.lax.scan(
             step, (zero, yt), jnp.arange(n_dev - 1)
         )
@@ -103,12 +102,12 @@ def fused_gemv_allreduce(mesh: Mesh, axis: str = "model"):
         out = jax.lax.all_gather(mine, axis, axis=1, tiled=False)
         return out.reshape(B, N)
 
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
         out_specs=P(None, None),
-        **SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
 
 
@@ -121,7 +120,7 @@ def ring_allreduce(mesh: Mesh, axis: str):
     """Bidirectional-naive ring all-reduce of a replicated-shape buffer."""
 
     def inner(x):
-        n_dev = axis_size(axis)
+        n_dev = jax.lax.axis_size(axis)
         perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
         def step(acc_x, _):
@@ -132,7 +131,7 @@ def ring_allreduce(mesh: Mesh, axis: str):
         (acc, _), _ = jax.lax.scan(step, (x, x), None, length=n_dev - 1)
         return acc
 
-    return shard_map(inner, mesh=mesh, in_specs=P(axis), out_specs=P(axis), **SHARD_MAP_NO_CHECK)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(axis), out_specs=P(axis), check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -175,9 +174,9 @@ def overlap_grad_allreduce(mesh: Mesh, axis: str = "data", *, compress: bool = F
                     return compressed_psum(gs, axis)
                 return jax.lax.psum(gs, axis)
 
-            return shard_map(
+            return jax.shard_map(
                 inner, mesh=mesh, in_specs=P(*(None,) * g.ndim),
-                out_specs=P(*(None,) * g.ndim), **SHARD_MAP_NO_CHECK,
+                out_specs=P(*(None,) * g.ndim), check_vma=False,
             )(g)
 
         return jax.tree.map(red, grads)

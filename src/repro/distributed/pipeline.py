@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import SHARD_MAP_NO_CHECK, shard_map
 
 __all__ = ["pipeline_apply", "bubble_fraction", "stack_stage_params"]
 
@@ -115,10 +114,10 @@ def pipeline_apply(
         return outs.reshape(B, *x.shape[1:])
 
     stage_spec = jax.tree.map(lambda _: P(axis), {"_": 0})  # placeholder
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        **SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated in interpret mode against ref.py oracles)."""
+"""Pallas TPU kernels, checked against the ref.py oracles: compiled on the
+chip (``chip_smoke.py``), in interpret mode on the CPU (``tests/test_kernels.py``)."""
 
 from . import ops, ref
 from .ops import decode_attention, gemv, gemv_tiles, remote_first_order, rmsnorm

@@ -41,7 +41,7 @@ def gemv_pallas(
     *,
     bm: int = 128,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     M, K = a.shape
     K2, N = x.shape

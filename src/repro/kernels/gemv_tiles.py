@@ -6,9 +6,9 @@ successor — remote-owned partial tiles are produced first (so their xGMI/ICI
 pushes can start while local tiles compute), local tiles last.  The tile
 permutation arrives via TPU scalar prefetch (``PrefetchScalarGridSpec``), the
 idiomatic mechanism for data-dependent BlockSpec index maps.  A progress
-output records which owner each grid step serviced, letting tests assert the
-remote-first schedule that the Eidola workload model times.  Values are
-identical to a plain GEMV.
+output, resident in SMEM for the whole grid, records which owner each grid
+step serviced, letting tests assert the remote-first schedule that the Eidola
+workload model times.  Values are identical to a plain GEMV.
 """
 
 from __future__ import annotations
@@ -51,7 +51,7 @@ def _kernel(order_ref, a_ref, x_ref, o_ref, prog_ref, *, tiles_per_dev):
     @pl.when(k == nk - 1)
     def _record():
         # which owner did this grid step service (schedule introspection)
-        prog_ref[0] = order_ref[t] // tiles_per_dev
+        prog_ref[t] = order_ref[t] // tiles_per_dev
 
 
 @functools.partial(
@@ -65,7 +65,7 @@ def gemv_tiles_pallas(
     my_dev: int,
     bm: int = 64,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (y [M,N] in a.dtype, owner_served i32[T]) over T grid tiles."""
     M, K = a.shape
@@ -86,7 +86,8 @@ def gemv_tiles_pallas(
         ],
         out_specs=[
             pl.BlockSpec((bm, N), lambda t, k, order: (order[t], 0)),
-            pl.BlockSpec((1,), lambda t, k, order: (t,)),
+            # a (1,) VMEM block per step breaks Mosaic's rank-1 tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
     )
     y, prog = pl.pallas_call(

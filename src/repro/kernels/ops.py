@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels (+ dispatch helpers).
 
-``interpret=True`` everywhere in this container (CPU validation of the TPU
-kernel bodies); on real TPU hardware pass ``interpret=False`` and the same
-BlockSpecs compile to Mosaic.
+The kernels compile to Mosaic by default (``interpret=False``), so on a TPU
+they always run compiled.  ``interpret=True`` runs the same kernel bodies in
+the Pallas interpreter, for checking values on a CPU; it is slow and its
+timings say nothing about the compiled kernel.
 """
 
 from __future__ import annotations

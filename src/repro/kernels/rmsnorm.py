@@ -31,7 +31,7 @@ def rmsnorm_pallas(
     *,
     br: int = 256,
     eps: float = 1e-6,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     orig_shape = x.shape
     D = x.shape[-1]

@@ -1,7 +1,13 @@
 import os
+# A CPU placeholder tool: 512 virtual host devices stand in for the pod, on
+# every machine (a TPU host would otherwise hand jax its real chips).  MUST
+# precede every other import (jax locks platform and device count at init).
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
+
+Runs on 512 virtual CPU devices, never on an accelerator: what it gives is
+the partitioned program (bytes, FLOPs, collectives), not a device time.
 
 For each cell this produces, with ZERO device allocation:
   - ``compiled.memory_analysis()``  -> bytes/device (does it fit HBM?)

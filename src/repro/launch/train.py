@@ -21,6 +21,8 @@ import jax.numpy as jnp
 
 from repro.configs import REGISTRY, get_config, reduced
 from repro.data import DataConfig, SyntheticLMDataset, prefetch
+from repro.launch.cache import use_checkout_cache
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.optim import AdamWConfig
 from repro.training import TrainConfig, Trainer
@@ -45,6 +47,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args()
+    use_checkout_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -61,7 +64,7 @@ def main() -> None:
           f"({model.n_active_params()/1e6:.1f}M active), mesh={args.mesh}")
 
     dims = tuple(int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh(dims, ("data", "model")[: len(dims)])
+    mesh = make_mesh(dims, ("data", "model")[: len(dims)])
 
     tcfg = TrainConfig(
         microbatches=args.microbatches,

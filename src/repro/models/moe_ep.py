@@ -33,8 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import SHARD_MAP_NO_CHECK, axis_size, shard_map
-
 from .common import ModelConfig
 
 __all__ = ["moe_apply_ep", "ep_applicable"]
@@ -103,7 +101,7 @@ def _ep_scatter_body(cfg: ModelConfig, reduce_axes, ff_axis, p, x_blk):
         p["w_gate"] = jax.lax.all_gather(p["w_gate"], ff_axis, axis=2, tiled=True)
         p["w_up"] = jax.lax.all_gather(p["w_up"], ff_axis, axis=2, tiled=True)
         p["w_down"] = jax.lax.all_gather(p["w_down"], ff_axis, axis=1, tiled=True)
-    msz = axis_size("model")
+    msz = jax.lax.axis_size("model")
     midx = jax.lax.axis_index("model")
     E_loc = cfg.n_experts // msz
     k = cfg.experts_per_token
@@ -171,7 +169,7 @@ def _ep_scatter_body(cfg: ModelConfig, reduce_axes, ff_axis, p, x_blk):
 
 def _ep_gather_body(cfg: ModelConfig, reduce_axes, ff_axis, p, x_blk):
     B_loc, S, d = x_blk.shape
-    msz = axis_size("model")
+    msz = jax.lax.axis_size("model")
     midx = jax.lax.axis_index("model")
     E_loc = cfg.n_experts // msz
     k = cfg.experts_per_token
@@ -250,12 +248,12 @@ def moe_apply_ep(
     p_used = {k: p[k] for k in param_specs}
 
     body = _ep_scatter_body if use_scatter else _ep_gather_body
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(body, cfg, tuple(batch_axes), ff_axis),
         mesh=mesh,
         in_specs=(param_specs, x_spec),
         out_specs=(x_spec, P(None)),
-        **SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
     y, aux = fn(p_used, x)
     return y, {

@@ -29,7 +29,6 @@ from repro.distributed import (
     param_shardings,
     zero1_shardings,
 )
-from repro.distributed.compat import require_sharding_invariant_rng
 from repro.distributed.zero import zero1_from_params
 from repro.ft import SimulatedFailure, StragglerMonitor
 from repro.models import Model
@@ -63,10 +62,6 @@ def build_train_step(
     rules: ShardingRules = DEFAULT_RULES,
 ):
     """Returns (train_step_jitted, shardings dict, fallback log)."""
-    # the trainer's contract is mesh-shape-invariant determinism (same seed,
-    # same values on (1,1) and (2,4) meshes) — jax 0.4's legacy threefry
-    # breaks that for sharded init, so force the partitionable RNG here
-    require_sharding_invariant_rng()
     specs = model.param_specs()
     axes_tree = model.param_axes()
     abstract_params = model.abstract_params()

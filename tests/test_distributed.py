@@ -37,8 +37,9 @@ def test_fused_gemv_allreduce_equals_psum():
     run_devices(
         """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.distributed.collectives import psum_matmul, fused_gemv_allreduce
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 256), jnp.float32)
 w = jax.random.normal(jax.random.PRNGKey(1), (256, 64), jnp.float32) * 0.05
 y1 = jax.jit(psum_matmul(mesh))(x, w)
@@ -53,6 +54,7 @@ def test_ep_moe_matches_local_oracle_and_grads():
     run_devices(
         """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.models.common import ModelConfig, materialize
 from repro.models.moe import moe_apply, moe_specs
 from repro.models.moe_ep import moe_apply_ep
@@ -62,7 +64,7 @@ cfg = ModelConfig(n_layers=1, d_model=32, n_heads=2, n_kv_heads=2, d_ff=48,
                   n_shared_experts=1, capacity_factor=4.0,
                   param_dtype=jnp.float32)
 p = materialize(moe_specs(cfg), jax.random.PRNGKey(0))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32), jnp.float32) * 0.5
 y_local, _ = moe_apply(cfg, p, x)
 y_ep, _ = jax.jit(lambda p, x: moe_apply_ep(cfg, p, x, mesh))(p, x)
@@ -86,6 +88,7 @@ def test_sharded_train_step_matches_single_device():
     out = run_devices(
         """
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.models import Model, ModelConfig
 from repro.training import TrainConfig, build_train_step
 from repro.optim import AdamWConfig, adamw_init
@@ -97,7 +100,7 @@ tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 128)
 lab = jnp.roll(tok, -1, axis=1)
 losses = []
 for dims in ((1, 1), (2, 4)):
-    mesh = jax.make_mesh(dims, ("data", "model"))
+    mesh = make_mesh(dims, ("data", "model"))
     model = Model(cfg, mesh=mesh)
     tcfg = TrainConfig(optim=AdamWConfig(lr=1e-3), donate_state=False)
     step, sh, fb = build_train_step(model, mesh, tcfg)
@@ -125,6 +128,7 @@ def test_sharded_train_step_bf16_across_mesh_shapes():
     out = run_devices(
         """
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.models import Model, ModelConfig
 from repro.training import TrainConfig, build_train_step
 from repro.optim import AdamWConfig, adamw_init
@@ -135,7 +139,7 @@ tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 128)
 lab = jnp.roll(tok, -1, axis=1)
 losses = []
 for dims in ((1, 1), (2, 4), (4, 2)):
-    mesh = jax.make_mesh(dims, ("data", "model"))
+    mesh = make_mesh(dims, ("data", "model"))
     model = Model(cfg, mesh=mesh)
     tcfg = TrainConfig(optim=AdamWConfig(lr=1e-3), donate_state=False)
     step, sh, fb = build_train_step(model, mesh, tcfg)
@@ -163,6 +167,7 @@ def test_indivisible_dims_fall_back_to_replication():
     run_devices(
         """
 import jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config, reduced
 from repro.models import Model
 from repro.distributed import param_shardings, DEFAULT_RULES
@@ -170,7 +175,7 @@ from repro.distributed import param_shardings, DEFAULT_RULES
 # FULL config, abstract only (no allocation): vocab 73448 % 16 != 0
 cfg = get_config("minicpm3-4b")
 m = Model(cfg)
-mesh16 = jax.make_mesh((1, 16), ("data", "model"))
+mesh16 = make_mesh((1, 16), ("data", "model"))
 sh, fallbacks = param_shardings(m.param_axes(), m.abstract_params(), mesh16,
                                 DEFAULT_RULES)
 assert any("replicated" in f for f in fallbacks), fallbacks
@@ -178,7 +183,7 @@ assert any("replicated" in f for f in fallbacks), fallbacks
 # and a reduced model actually runs under resolved shardings
 cfg_r = reduced(get_config("gemma3-1b"))
 mr = Model(cfg_r)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 sh_r, _ = param_shardings(mr.param_axes(), mr.abstract_params(), mesh,
                           DEFAULT_RULES)
 params = jax.jit(mr.init, out_shardings=sh_r)(jax.random.PRNGKey(0))
@@ -215,13 +220,13 @@ def test_compressed_psum_accuracy():
     run_devices(
         """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import compressed_psum
-from repro.distributed.compat import SHARD_MAP_NO_CHECK, shard_map
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 64), jnp.float32)
-fn = shard_map(lambda x: compressed_psum(x, "data"), mesh=mesh,
-               in_specs=P("data"), out_specs=P("data"), **SHARD_MAP_NO_CHECK)
+fn = jax.shard_map(lambda x: compressed_psum(x, "data"), mesh=mesh,
+               in_specs=P("data"), out_specs=P("data"), check_vma=False)
 out = jax.jit(fn)(g)
 exact = np.broadcast_to(np.asarray(g).sum(0, keepdims=True), (8, 64))
 # int8 quantization bound: n_shards * max|g| / 127 (elementwise absolute)
@@ -237,8 +242,9 @@ def test_pipeline_parallel_matches_sequential():
     run_devices(
         """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.distributed.pipeline import pipeline_apply, stack_stage_params
-mesh = jax.make_mesh((4,), ("pipe",))
+mesh = make_mesh((4,), ("pipe",))
 L, d = 8, 16
 W = jax.random.normal(jax.random.PRNGKey(0), (L, d, d), jnp.float32) * 0.25
 b = jax.random.normal(jax.random.PRNGKey(1), (L, d), jnp.float32) * 0.1

@@ -13,6 +13,7 @@ import pytest
 
 from repro.checkpoint import CheckpointManager, load_pytree, save_pytree
 from repro.data import DataConfig, SyntheticLMDataset, prefetch
+from repro.launch.mesh import make_mesh
 from repro.ft import (
     ElasticMeshManager,
     HeartbeatMonitor,
@@ -39,7 +40,7 @@ def tiny_model():
 
 
 def test_loss_decreases_and_failure_recovery():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = tiny_model()
     data = SyntheticLMDataset(
         DataConfig(vocab=128, seq_len=64, global_batch=8, seed=1)
@@ -168,7 +169,7 @@ def test_remesh_preserves_values():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
-    mesh1 = jax.make_mesh((1,), ("data",))
+    mesh1 = make_mesh((1,), ("data",))
 
     def sh_fn(mesh):
         return {"w": NamedSharding(mesh, P())}

@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps vs. ref.py oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs. ref.py oracles (interpret mode: the
+kernels compile by default; these CPU tests ask for the interpreter)."""
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ def _tol(dtype):
 def test_gemv_sweep(M, K, N, dtype):
     a = jax.random.normal(RNG, (M, K), jnp.float32).astype(dtype)
     x = jax.random.normal(jax.random.PRNGKey(1), (K, N), jnp.float32).astype(dtype)
-    y = ops.gemv(a, x, bm=64, bk=256)
+    y = ops.gemv(a, x, bm=64, bk=256, interpret=True)
     np.testing.assert_allclose(
         np.asarray(y, np.float32), np.asarray(ref.gemv_ref(a, x), np.float32),
         **_tol(dtype),
@@ -34,7 +35,8 @@ def test_gemv_tiles_values_and_schedule(n_dev, my_dev):
     M, K = 256, 1024
     a = jax.random.normal(RNG, (M, K), jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (K, 1), jnp.float32)
-    y, prog = ops.gemv_tiles(a, x, n_dev=n_dev, my_dev=my_dev, bm=32, bk=256)
+    y, prog = ops.gemv_tiles(a, x, n_dev=n_dev, my_dev=my_dev, bm=32, bk=256,
+                             interpret=True)
     np.testing.assert_allclose(
         y, ref.gemv_tiles_ref(a, x, n_dev, my_dev), rtol=3e-5, atol=3e-5
     )
@@ -56,7 +58,8 @@ def test_decode_attention_sweep(B, H, KV, D, S, dtype):
     k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KV, D), jnp.float32).astype(dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KV, D), jnp.float32).astype(dtype)
     length = S - 7
-    o = ops.decode_attention(q, k, v, jnp.int32(length), bs=256)
+    o = ops.decode_attention(q, k, v, jnp.int32(length), bs=256,
+                             interpret=True)
     o_ref = ref.decode_attention_ref(q, k, v, length)
     np.testing.assert_allclose(
         np.asarray(o, np.float32), np.asarray(o_ref, np.float32), **_tol(dtype)
@@ -68,11 +71,13 @@ def test_decode_attention_respects_length_mask():
     q = jax.random.normal(RNG, (B, H, D), jnp.float32)
     k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KV, D), jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KV, D), jnp.float32)
-    o_small = ops.decode_attention(q, k, v, jnp.int32(10), bs=64)
+    o_small = ops.decode_attention(q, k, v, jnp.int32(10), bs=64,
+                                   interpret=True)
     # garbage beyond the length must not affect the result
     k2 = k.at[:, 10:].set(99.0)
     v2 = v.at[:, 10:].set(-99.0)
-    o_small2 = ops.decode_attention(q, k2, v2, jnp.int32(10), bs=64)
+    o_small2 = ops.decode_attention(q, k2, v2, jnp.int32(10), bs=64,
+                                    interpret=True)
     np.testing.assert_allclose(o_small, o_small2, rtol=1e-6, atol=1e-6)
 
 
@@ -81,7 +86,7 @@ def test_decode_attention_respects_length_mask():
 def test_rmsnorm_sweep(shape, dtype):
     x = jax.random.normal(RNG, shape, jnp.float32).astype(dtype)
     g = jax.random.normal(jax.random.PRNGKey(1), (shape[-1],), jnp.float32) * 0.2
-    y = ops.rmsnorm(x, g, br=32)
+    y = ops.rmsnorm(x, g, br=32, interpret=True)
     np.testing.assert_allclose(
         np.asarray(y, np.float32),
         np.asarray(ref.rmsnorm_ref(x, g), np.float32),

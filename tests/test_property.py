@@ -1,6 +1,5 @@
 """Hypothesis property tests on system invariants."""
 
-import jax
 import numpy as np
 import pytest
 
@@ -20,6 +19,7 @@ from repro.core import (
 )
 from repro.core.hlo_analyzer import analyze_hlo
 from repro.distributed.sharding import DEFAULT_RULES, resolve_spec
+from repro.launch.mesh import make_mesh
 
 # ---------------------------------------------------------------------------
 # WTT invariants
@@ -142,7 +142,7 @@ def test_resolve_spec_divisibility(dims, axes):
 
     n = min(len(dims), len(axes))
     dims, axes = dims[:n], axes[:n]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     # a fake 4x4 mesh is enough to test the table logic; use real mesh sizes
     spec = resolve_spec(dims, axes, DEFAULT_RULES, mesh, path="t")

@@ -84,6 +84,60 @@ def test_parse_collectives_kinds_and_groups():
     assert collective_bytes(ops) > 0
 
 
+# the shape of a TPU compile of a ring step: layouts on every type, async
+# -start/-done pairs, and the ppermute inside a 3-trip scan loop
+TPU_HLO = """
+%body (p: (s32[], f32[8,2048])) -> (s32[], f32[8,2048]) {
+  %p = (s32[]{:T(128)}, f32[8,2048]{1,0:T(8,128)S(1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%p), index=0
+  %x = f32[8,2048]{1,0:T(8,128)S(1)} get-tuple-element(%p), index=1
+  %collective-permute-start = (f32[8,2048]{1,0:T(8,128)S(1)}, f32[8,2048]{1,0:T(8,128)S(1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%x), channel_id=1, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
+  %collective-permute-done = f32[8,2048]{1,0:T(8,128)S(1)} collective-permute-done(%collective-permute-start)
+  %one = s32[]{:T(128)} constant(1)
+  %ni = s32[]{:T(128)} add(%i, %one)
+  ROOT %t = (s32[]{:T(128)}, f32[8,2048]{1,0:T(8,128)S(1)}) tuple(%ni, %collective-permute-done)
+}
+
+%cond (p: (s32[], f32[8,2048])) -> pred[] {
+  %p = (s32[]{:T(128)}, f32[8,2048]{1,0:T(8,128)S(1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%p), index=0
+  %n = s32[]{:T(128)} constant(3)
+  ROOT %lt = pred[]{:T(512)} compare(%i, %n), direction=LT
+}
+
+ENTRY %main (a: f32[8,2048]) -> f32[8,8192] {
+  %a = f32[8,2048]{1,0:T(8,128)S(1)} parameter(0)
+  %z = s32[]{:T(128)} constant(0)
+  %init = (s32[]{:T(128)}, f32[8,2048]{1,0:T(8,128)S(1)}) tuple(%z, %a)
+  %w = (s32[]{:T(128)}, f32[8,2048]{1,0:T(8,128)S(1)}) while(%init), condition=%cond, body=%body
+  %y = f32[8,2048]{1,0:T(8,128)S(1)} get-tuple-element(%w), index=1
+  ROOT %all-gather.5 = f32[8,8192]{1,0:T(8,128)S(1)} all-gather(%y), channel_id=2, replica_groups={{0,1,2,3}}, dimensions={1}, use_global_device_ids=true
+}
+"""
+
+
+def test_parse_collectives_reads_tpu_layouts_and_permute_pairs():
+    ops = parse_collectives(TPU_HLO)
+    # one entry per op (the -done half carries no traffic); the permute's
+    # group is the four devices its source/target pairs name
+    assert [(o.kind, o.group_size, o.result_bytes) for o in ops] == [
+        ("collective-permute", 4, 8 * 2048 * 4),
+        ("all-gather", 4, 8 * 8192 * 4),
+    ]
+    assert [o.group_size for o in parse_collectives(HLO_SNIPPET)
+            if o.kind == "collective-permute"] == [2]
+
+
+def test_analyzer_counts_permute_executions_in_scan_loop():
+    from repro.core.hlo_analyzer import analyze_hlo
+
+    by_kind = analyze_hlo(TPU_HLO).collectives_by_kind()
+    # trip-count aware: 3 executions of one 64 KiB buffer, not the start
+    # tuple's summed buffers
+    assert by_kind["collective-permute"] == (3.0, 3.0 * 8 * 2048 * 4)
+    assert by_kind["all-gather"] == (1.0, 8 * 2048 * 4)
+
+
 def test_schedule_to_trace_replayable():
     ops = [CollectiveOp("all-reduce", 2**20, 2**20, 16),
            CollectiveOp("all-gather", 2**18, 2**14, 16)]
@@ -169,7 +223,8 @@ cfg = ModelConfig(n_layers=1, d_model=16, n_heads=2, n_kv_heads=2, d_ff=32,
                   vocab=64, n_experts=8, experts_per_token=4,
                   capacity_factor=0.25, param_dtype=jnp.float32)
 p = materialize(moe_specs(cfg), jax.random.PRNGKey(0))
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, 4), ("data", "model"))
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 16), jnp.float32)
 y, aux = jax.jit(lambda p, x: moe_apply_ep(cfg, p, x, mesh))(p, x)
 assert float(aux["moe_dropped"]) > 0, "tiny capacity must drop tokens"

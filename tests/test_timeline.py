@@ -542,9 +542,10 @@ def test_replay_numpy_matches_real_run():
 
 
 def test_replay_jax_matches_numpy():
-    jax = pytest.importorskip("jax")  # noqa: F841
-    from repro.core.cohort_timeline import replay_lane_jax
+    jax = pytest.importorskip("jax")
+    from repro.core.cohort_timeline import lane_int32, replay_lane_jax
 
+    replay = jax.jit(replay_lane_jax, static_argnames=("poll", "check"))
     rng = np.random.default_rng(7)
     for _ in range(10):
         n_steps = rng.integers(1, 30)
@@ -558,6 +559,34 @@ def test_replay_jax_matches_numpy():
         dispatch = rng.integers(0, 300, n_cohorts).astype(np.int64)
         r_np, t_np = replay_lane_numpy(dispatch, is_wait, val, poll=64,
                                        check=4)
-        r_jx, t_jx = replay_lane_jax(dispatch, is_wait, val, poll=64, check=4)
+        r_jx, t_jx = replay(
+            *lane_int32(dispatch, is_wait, val, poll=64, check=4),
+            poll=64, check=4,
+        )
         np.testing.assert_array_equal(r_np, np.asarray(r_jx, np.int64))
         np.testing.assert_array_equal(t_np, np.asarray(t_jx, np.int64))
+
+
+def test_replay_jax_refuses_what_int32_cannot_hold():
+    """The int32 device replay never wraps: the host boundary proves the
+    range or raises, and unchecked 64-bit inputs are refused."""
+    from repro.core.cohort_timeline import lane_int32, replay_lane_jax
+
+    is_wait = np.array([False, True, False])
+    dispatch = np.array([0, 5], np.int64)
+    top = np.iinfo(np.int32).max
+    # just inside: the bound is dispatch + durations + waits * check
+    ok = np.array([top - 3000, 1000, 1000], np.int64)
+    lane_int32(dispatch, is_wait, ok, poll=64, check=4)
+    # a long timed step past 2**31 would wrap a plain int32 scan
+    big = np.array([top, 1000, 1000], np.int64)
+    with pytest.raises(OverflowError, match="int32"):
+        lane_int32(dispatch, is_wait, big, poll=64, check=4)
+    # a flag that only becomes visible past 2**31 - poll
+    late = np.array([10, top - 10, 10], np.int64)
+    with pytest.raises(OverflowError):
+        lane_int32(dispatch, is_wait, late, poll=64, check=4)
+    with pytest.raises(ValueError):
+        lane_int32(-dispatch - 1, is_wait, ok, poll=64, check=4)
+    with pytest.raises(TypeError, match="lane_int32"):
+        replay_lane_jax(dispatch, is_wait, ok, poll=64, check=4)
