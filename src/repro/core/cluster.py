@@ -26,7 +26,6 @@ cycle/event runs stay bit-identical, which the tests assert per scenario.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Union
 
@@ -37,6 +36,7 @@ from .interconnect import InterconnectSpec, build_fabric
 from .memory import DirectoryMemory
 from .monitor import MonitorLog
 from .scenario import EmitOp, PhaseSpec, Scenario, SymbolicProgram
+from .spans import span
 from .target import TargetDevice
 from .topology import V5E, FabricModel, Topology
 from .wtt import LazyWriteRun, RegistrationLike, WriteTrackingTable
@@ -158,85 +158,86 @@ class Cluster:
         plan_cache=None,
         plan_key=None,
     ):
-        self.cfg = cfg.validate()
-        self.scenario = scenario
-        self.amap = scenario.amap
-        self.perturb = perturb
-        self.collect_segments = collect_segments
-        # optional cross-run lockstep plan cache (sweeps revisiting the
-        # same shape skip recompilation; plans are read-only at run time)
-        self._plan_cache = plan_cache
-        self._plan_key = plan_key
-        # None = auto (use the timeline engine when eligible), True = require
-        # it (error when ineligible), False = never
-        self._timeline = timeline
-        # same tri-state for the bulk lockstep solver, which substitutes for
-        # the timeline engine on rank-uniform symbolic programs
-        self._lockstep = lockstep
-        self._cohorts_flag = cohorts
-        self.fabric = resolve_cluster_fabric(
-            self.cfg, scenario, fabric=fabric, topology=topology
-        )
-        if sanitize:
-            # late import: repro.analysis imports this module
-            from repro.analysis.sanitize import TrafficSanitizer
-
-            self._san = TrafficSanitizer(
-                self.amap, self.fabric, cfg.n_devices
+        with span("cluster.init"):
+            self.cfg = cfg.validate()
+            self.scenario = scenario
+            self.amap = scenario.amap
+            self.perturb = perturb
+            self.collect_segments = collect_segments
+            # optional cross-run lockstep plan cache (sweeps revisiting the
+            # same shape skip recompilation; plans are read-only at run time)
+            self._plan_cache = plan_cache
+            self._plan_key = plan_key
+            # None = auto (use the timeline engine when eligible), True = require
+            # it (error when ineligible), False = never
+            self._timeline = timeline
+            # same tri-state for the bulk lockstep solver, which substitutes for
+            # the timeline engine on rank-uniform symbolic programs
+            self._lockstep = lockstep
+            self._cohorts_flag = cohorts
+            self.fabric = resolve_cluster_fabric(
+                self.cfg, scenario, fabric=fabric, topology=topology
             )
-        else:
-            self._san = None
-        self._seq = 0  # cluster-wide emission seq counter (plain int: hot path)
-        # (src_device, phase_idx, emit_idx) -> completions seen (coalescing)
-        self._emit_counts: Dict[tuple, int] = {}
-        # dst device -> marker data writes placed so far (address spacing)
-        self._data_marks: Dict[int, int] = {}
+            if sanitize:
+                # late import: repro.analysis imports this module
+                from repro.analysis.sanitize import TrafficSanitizer
 
-        t0 = time.perf_counter()
-        self.nodes: List[ClusterNode] = []
-        for d in range(cfg.n_devices):
-            memory = DirectoryMemory(self.amap)
-            monitor = (
-                MonitorLog(
+                self._san = TrafficSanitizer(
+                    self.amap, self.fabric, cfg.n_devices
+                )
+            else:
+                self._san = None
+            self._seq = 0  # cluster-wide emission seq counter (plain int: hot path)
+            # (src_device, phase_idx, emit_idx) -> completions seen (coalescing)
+            self._emit_counts: Dict[tuple, int] = {}
+            # dst device -> marker data writes placed so far (address spacing)
+            self._data_marks: Dict[int, int] = {}
+
+        with span("program.build") as build:
+            self.nodes: List[ClusterNode] = []
+            for d in range(cfg.n_devices):
+                memory = DirectoryMemory(self.amap)
+                monitor = (
+                    MonitorLog(
+                        memory,
+                        semantics=cfg.monitor_semantics,  # type: ignore[arg-type]
+                        wake_latency_cycles=cfg.wake_latency_cycles,
+                    )
+                    if cfg.sync == SyncPolicy.SYNCMON
+                    else None
+                )
+                target = TargetDevice(
+                    cfg,
+                    scenario,
                     memory,
-                    semantics=cfg.monitor_semantics,  # type: ignore[arg-type]
-                    wake_latency_cycles=cfg.wake_latency_cycles,
+                    monitor,
+                    perturb=self._perturb_for(d),
+                    device_id=d,
+                    emit_sink=self._on_emit,
+                    cohorts=cohorts,
                 )
-                if cfg.sync == SyncPolicy.SYNCMON
-                else None
-            )
-            target = TargetDevice(
-                cfg,
-                scenario,
-                memory,
-                monitor,
-                perturb=self._perturb_for(d),
-                device_id=d,
-                emit_sink=self._on_emit,
-                cohorts=cohorts,
-            )
-            wtt = WriteTrackingTable(clock_ghz=cfg.clock_ghz)
-            if self._san is not None:
-                memory.add_write_observer(self._san.observer_for(d))
-            self.nodes.append(ClusterNode(d, memory, monitor, target, wtt))
-
-        # seed traces (the open-loop degenerate case / warm-start writes) get
-        # the same xGMI visibility treatment as the Eidola facade
-        for node in self.nodes:
-            for w in scenario.traces_for(node.device_id):
-                eff = replace(
-                    w, wakeup_ns=w.wakeup_ns + cfg.xgmi_enact_latency_ns
-                )
-                p = self._perturb_for(node.device_id)
-                if p is not None:
-                    eff = p.jitter_write(eff)
+                wtt = WriteTrackingTable(clock_ghz=cfg.clock_ghz)
                 if self._san is not None:
-                    self._san.note_seed_write(node.device_id, eff.addr)
-                node.wtt.register(eff)
+                    memory.add_write_observer(self._san.observer_for(d))
+                self.nodes.append(ClusterNode(d, memory, monitor, target, wtt))
+
+            # seed traces (the open-loop degenerate case / warm-start writes) get
+            # the same xGMI visibility treatment as the Eidola facade
+            for node in self.nodes:
+                for w in scenario.traces_for(node.device_id):
+                    eff = replace(
+                        w, wakeup_ns=w.wakeup_ns + cfg.xgmi_enact_latency_ns
+                    )
+                    p = self._perturb_for(node.device_id)
+                    if p is not None:
+                        eff = p.jitter_write(eff)
+                    if self._san is not None:
+                        self._san.note_seed_write(node.device_id, eff.addr)
+                    node.wtt.register(eff)
         # program-construction wall (nodes + seed traces), surfaced in
         # Report.meta["program_stats"] — symbolic programs keep this O(1)
         # per rank in step count where flat construction was O(steps)
-        self._construct_wall_s = time.perf_counter() - t0
+        self._construct_wall_s = build.dur
 
     # ------------------------------------------------------------------
     # emission: phase completion -> fabric -> destination WTT
@@ -465,57 +466,55 @@ class Cluster:
         use_timeline = False
         lockstep_used = False
         tl_reason: Optional[str] = None
-        if cfg.engine == EngineKind.EVENT and self._timeline is not False:
-            if not self._cohorts_flag:
-                tl_reason = "cohorts=False forces the per-workgroup interpreter"
-            else:
-                from .cohort_timeline import timeline_support
+        # the bulk lockstep solver substitutes for the timeline engine when
+        # every rank (or every rank of each program group, on the multi-tier
+        # presets) runs a group-uniform symbolic program; anything else
+        # falls back to the generic timeline
+        ls_reason: Optional[str] = None
+        ls_engine = None
+        cache, key, cached = self._plan_cache, self._plan_key, None
+        with span("engine.select"):
+            if cfg.engine == EngineKind.EVENT and self._timeline is not False:
+                if not self._cohorts_flag:
+                    tl_reason = "cohorts=False forces the per-workgroup interpreter"
+                else:
+                    from .cohort_timeline import timeline_support
 
-                tl_reason = timeline_support(self)
-            use_timeline = tl_reason is None
-        elif self._timeline is True:
-            tl_reason = "timeline engine requires EngineKind.EVENT"
-        if self._timeline is True and not use_timeline:
-            raise ValueError(
-                f"timeline engine requested but unavailable: {tl_reason}"
-            )
-        if self._lockstep is True and not use_timeline:
-            raise ValueError(
-                "lockstep solver requested but unavailable: it substitutes "
-                "for the timeline engine, which is not in use here "
-                f"({tl_reason or 'engine is not EngineKind.EVENT'})"
-            )
-        lockstep_reason: Optional[str] = None
-        if use_timeline:
-            # the bulk lockstep solver substitutes for the timeline engine
-            # when every rank (or every rank of each program group, on the
-            # multi-tier presets) runs a group-uniform symbolic program;
-            # anything else falls back to the generic timeline
-            ls_reason: Optional[str] = None
-            ls_engine = None
-            if self._lockstep is not False:
+                    tl_reason = timeline_support(self)
+                use_timeline = tl_reason is None
+            elif self._timeline is True:
+                tl_reason = "timeline engine requires EngineKind.EVENT"
+            if self._timeline is True and not use_timeline:
+                raise ValueError(
+                    f"timeline engine requested but unavailable: {tl_reason}"
+                )
+            if self._lockstep is True and not use_timeline:
+                raise ValueError(
+                    "lockstep solver requested but unavailable: it substitutes "
+                    "for the timeline engine, which is not in use here "
+                    f"({tl_reason or 'engine is not EngineKind.EVENT'})"
+                )
+            if use_timeline and self._lockstep is not False:
                 from .lockstep import LockstepEngine, lockstep_support
 
                 ls_reason = lockstep_support(self)
                 if ls_reason is None:
                     ls_engine = LockstepEngine(self)
-                    cache = self._plan_cache
-                    key = self._plan_key
-                    cached = (
-                        cache.get(key)
-                        if cache is not None and key is not None
-                        else None
-                    )
-                    ls_reason = ls_engine.compile(reuse=cached)
-                    if (
-                        ls_reason is None
-                        and cached is None
-                        and cache is not None
-                        and key is not None
-                    ):
-                        cache[key] = ls_engine.plan_handle()
-            else:
+                    if cache is not None and key is not None:
+                        cached = cache.get(key)
+            elif use_timeline:
                 ls_reason = "lockstep=False disables the bulk solver"
+        if ls_engine is not None:
+            ls_reason = ls_engine.compile(reuse=cached)
+            if (
+                ls_reason is None
+                and cached is None
+                and cache is not None
+                and key is not None
+            ):
+                cache[key] = ls_engine.plan_handle()
+        lockstep_reason: Optional[str] = None
+        if use_timeline:
             if self._lockstep is True and ls_reason is not None:
                 raise ValueError(
                     f"lockstep solver requested but unavailable: {ls_reason}"
@@ -540,7 +539,8 @@ class Cluster:
             if res is None:
                 from .cohort_timeline import TimelineEngine
 
-                res = TimelineEngine(self).run()
+                with span("timeline.run"):
+                    res = TimelineEngine(self).run()
             lockstep_reason = "engaged" if lockstep_used else ls_reason
             engine_name = "event"  # same semantics & counters as the event
             # engine; meta["engine_impl"] records the implementation
@@ -560,79 +560,80 @@ class Cluster:
         if self._san is not None:
             self._san.check()
 
-        traffic: Dict[str, int] = {}
-        per_device: Dict[int, Dict[str, int]] = {}
-        monitor_stats: Dict[str, int] = {}
-        segments: List[Segment] = []
-        spans: Dict[int, float] = {}
-        for node in self.nodes:
-            td = node.memory.traffic.as_dict()
-            per_device[node.device_id] = td
-            for k, v in td.items():
-                traffic[k] = traffic.get(k, 0) + v
-            if node.monitor is not None:
-                for k, v in node.monitor.stats.items():
-                    monitor_stats[k] = monitor_stats.get(k, 0) + v
-            spans[node.device_id] = cfg.cycles_to_ns(
-                node.target.kernel_end_cycle
-            )
-            if self.collect_segments:
-                segments.extend(node.target.collect_segments())
-        # symbolic-vs-materialized program accounting (after the run, so the
-        # materialized count reflects what the engines actually expanded)
-        progs: Dict[int, object] = {}
-        for node in self.nodes:
-            for c in node.target.cohorts:
-                progs.setdefault(id(c.phases), c.phases)
-        sym = [p for p in progs.values() if isinstance(p, SymbolicProgram)]
-        program_stats = {
-            "symbolic_programs": len(sym),
-            "flat_programs": len(progs) - len(sym),
-            "segments": sum(len(p.segments) for p in sym),
-            "program_phases": sum(len(p) for p in progs.values()),
-            "materialized_phases": sum(len(p._memo) for p in sym)
-            + sum(
-                len(p)
-                for p in progs.values()
-                if not isinstance(p, SymbolicProgram)
-            ),
-            "construct_wall_s": self._construct_wall_s,
-            "lockstep": lockstep_used,
-        }
-        return Report(
-            engine=engine_name,
-            sync=cfg.sync.value,
-            traffic=traffic,
-            flag_reads=traffic.get("flag_reads", 0),
-            nonflag_reads=traffic.get("nonflag_reads", 0),
-            kernel_span_ns=max(spans.values()) if spans else 0.0,
-            sim_cycles=res.sim_cycles,
-            wall_time_s=res.wall_time_s,
-            wtt_registered=sum(n.wtt.stats.registered for n in self.nodes),
-            wtt_enacted=sum(n.wtt.stats.enacted for n in self.nodes),
-            wtt_head_polls=res.head_polls,
-            scenario=self.scenario.name,
-            monitor_stats=monitor_stats,
-            segments=segments,
-            meta={
-                "closed_loop": True,
-                "sanitized": self._san is not None,
-                "engine_impl": "timeline" if use_timeline else engine_name,
-                "lockstep_reason": lockstep_reason,
-                "program_stats": program_stats,
-                **(
-                    {"wall_breakdown": res.breakdown}
-                    if res.breakdown is not None
-                    else {}
+        with span("entry.report"):
+            traffic: Dict[str, int] = {}
+            per_device: Dict[int, Dict[str, int]] = {}
+            monitor_stats: Dict[str, int] = {}
+            segments: List[Segment] = []
+            spans: Dict[int, float] = {}
+            for node in self.nodes:
+                td = node.memory.traffic.as_dict()
+                per_device[node.device_id] = td
+                for k, v in td.items():
+                    traffic[k] = traffic.get(k, 0) + v
+                if node.monitor is not None:
+                    for k, v in node.monitor.stats.items():
+                        monitor_stats[k] = monitor_stats.get(k, 0) + v
+                spans[node.device_id] = cfg.cycles_to_ns(
+                    node.target.kernel_end_cycle
+                )
+                if self.collect_segments:
+                    segments.extend(node.target.collect_segments())
+            # symbolic-vs-materialized program accounting (after the run, so the
+            # materialized count reflects what the engines actually expanded)
+            progs: Dict[int, object] = {}
+            for node in self.nodes:
+                for c in node.target.cohorts:
+                    progs.setdefault(id(c.phases), c.phases)
+            sym = [p for p in progs.values() if isinstance(p, SymbolicProgram)]
+            program_stats = {
+                "symbolic_programs": len(sym),
+                "flat_programs": len(progs) - len(sym),
+                "segments": sum(len(p.segments) for p in sym),
+                "program_phases": sum(len(p) for p in progs.values()),
+                "materialized_phases": sum(len(p._memo) for p in sym)
+                + sum(
+                    len(p)
+                    for p in progs.values()
+                    if not isinstance(p, SymbolicProgram)
                 ),
-                "device_spans_ns": spans,
-                "fabric": dict(self.fabric.stats),
-                "fabric_name": self.fabric.spec.name,
-                "n_nodes": self.fabric.n_nodes,
-                "devices_per_node": self.fabric.devices_per_node,
-                **{f"param_{k}": v for k, v in self.scenario.params.items()},
-            },
-            n_devices=cfg.n_devices,
-            per_device=per_device,
-            closed_loop=True,
-        )
+                "construct_wall_s": self._construct_wall_s,
+                "lockstep": lockstep_used,
+            }
+            return Report(
+                engine=engine_name,
+                sync=cfg.sync.value,
+                traffic=traffic,
+                flag_reads=traffic.get("flag_reads", 0),
+                nonflag_reads=traffic.get("nonflag_reads", 0),
+                kernel_span_ns=max(spans.values()) if spans else 0.0,
+                sim_cycles=res.sim_cycles,
+                wall_time_s=res.wall_time_s,
+                wtt_registered=sum(n.wtt.stats.registered for n in self.nodes),
+                wtt_enacted=sum(n.wtt.stats.enacted for n in self.nodes),
+                wtt_head_polls=res.head_polls,
+                scenario=self.scenario.name,
+                monitor_stats=monitor_stats,
+                segments=segments,
+                meta={
+                    "closed_loop": True,
+                    "sanitized": self._san is not None,
+                    "engine_impl": "timeline" if use_timeline else engine_name,
+                    "lockstep_reason": lockstep_reason,
+                    "program_stats": program_stats,
+                    **(
+                        {"wall_breakdown": res.breakdown}
+                        if res.breakdown is not None
+                        else {}
+                    ),
+                    "device_spans_ns": spans,
+                    "fabric": dict(self.fabric.stats),
+                    "fabric_name": self.fabric.spec.name,
+                    "n_nodes": self.fabric.n_nodes,
+                    "devices_per_node": self.fabric.devices_per_node,
+                    **{f"param_{k}": v for k, v in self.scenario.params.items()},
+                },
+                n_devices=cfg.n_devices,
+                per_device=per_device,
+                closed_loop=True,
+            )

@@ -24,10 +24,10 @@ other's WTTs mid-run (closed-loop clusters).
 from __future__ import annotations
 
 import heapq
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from .spans import count, span
 from .target import EidolaDeadlock, TargetDevice
 from .wtt import WriteTrackingTable
 
@@ -43,8 +43,9 @@ class EngineResult:
     sim_cycles: int
     wall_time_s: float
     head_polls: int
-    # perf_counter section split (interpreter/fabric/WTT seconds); only the
-    # timeline engine fills this in — bench rows surface it as wall_breakdown
+    # seconds by section, surfaced as Report.meta["wall_breakdown"]: the
+    # lockstep solvers' compile/solve/writeback spans, or the timeline
+    # engine's interpreter/fabric/WTT accumulators
     breakdown: Optional[Dict[str, float]] = None
 
 
@@ -115,47 +116,47 @@ class CyclePollEngine:
         return self.run_nodes([(device, wtt)])
 
     def run_nodes(self, nodes: Sequence[Node]) -> EngineResult:
-        t0 = time.perf_counter()
-        cycle = -1
-        while not _all_idle(nodes):
-            cycle += 1
-            if cycle > _MAX_CYCLES:
-                # not the empty-queue deadlock: queues still hold work, the
-                # simulation just ran away — report what is pending instead
-                scenario = nodes[0][0].scenario.name or "<unnamed>"
-                pending = sum(len(wtt) for _, wtt in nodes)
-                blocked = sum(dev.blocked_count() for dev, _ in nodes)
-                raise EidolaDeadlock(
-                    f"scenario {scenario!r} exceeded {_MAX_CYCLES} cycles with "
-                    f"{pending} WTT writes pending and {blocked} workgroups "
-                    "blocked (runaway span or livelock, not an empty-queue "
-                    "deadlock)"
-                )
-            # (1) the per-cycle O(1) head check on every device; enact due
-            # writes everywhere before any device transition fires
-            for dev, wtt in nodes:
-                due = wtt.poll(cycle)
-                if due:
-                    for w in due:
-                        dev.memory.enact_xgmi_write(w, cycle)
-                    dev.on_writes_enacted(due, cycle)
-            # (2) fire device transitions scheduled at this cycle
-            any_pending = False
-            for dev, wtt in nodes:
-                nxt = dev.next_transition_cycle()
-                if nxt is not None:
-                    any_pending = True
-                    if nxt <= cycle:
-                        dev.process_until(cycle)
-            if (
-                not any_pending
-                and all(wtt.empty for _, wtt in nodes)
-                and not all(dev.all_done for dev, _ in nodes)
-            ):
-                raise _deadlock_error(nodes, cycle)
+        with span("engine.run") as run:
+            cycle = -1
+            while not _all_idle(nodes):
+                cycle += 1
+                if cycle > _MAX_CYCLES:
+                    # not the empty-queue deadlock: queues still hold work, the
+                    # simulation just ran away — report what is pending instead
+                    scenario = nodes[0][0].scenario.name or "<unnamed>"
+                    pending = sum(len(wtt) for _, wtt in nodes)
+                    blocked = sum(dev.blocked_count() for dev, _ in nodes)
+                    raise EidolaDeadlock(
+                        f"scenario {scenario!r} exceeded {_MAX_CYCLES} cycles with "
+                        f"{pending} WTT writes pending and {blocked} workgroups "
+                        "blocked (runaway span or livelock, not an empty-queue "
+                        "deadlock)"
+                    )
+                # (1) the per-cycle O(1) head check on every device; enact due
+                # writes everywhere before any device transition fires
+                for dev, wtt in nodes:
+                    due = wtt.poll(cycle)
+                    if due:
+                        for w in due:
+                            dev.memory.enact_xgmi_write(w, cycle)
+                        dev.on_writes_enacted(due, cycle)
+                # (2) fire device transitions scheduled at this cycle
+                any_pending = False
+                for dev, wtt in nodes:
+                    nxt = dev.next_transition_cycle()
+                    if nxt is not None:
+                        any_pending = True
+                        if nxt <= cycle:
+                            dev.process_until(cycle)
+                if (
+                    not any_pending
+                    and all(wtt.empty for _, wtt in nodes)
+                    and not all(dev.all_done for dev, _ in nodes)
+                ):
+                    raise _deadlock_error(nodes, cycle)
         return EngineResult(
             sim_cycles=max(cycle, 0),
-            wall_time_s=time.perf_counter() - t0,
+            wall_time_s=run.dur,
             head_polls=sum(wtt.stats.head_polls for _, wtt in nodes),
         )
 
@@ -172,6 +173,8 @@ class EventQueueEngine:
     cluster costs O(log N) per event instead of the former O(N) scan of every
     WTT head and device queue.  Intra-cycle ordering is unchanged: writes
     enact before device transitions at equal cycles, devices in id order.
+    The calendar entries acted on are counted as ``engine.events``
+    (:func:`repro.core.spans.count`).
     """
 
     name = "event"
@@ -182,89 +185,93 @@ class EventQueueEngine:
         return self.run_nodes([(device, wtt)])
 
     def run_nodes(self, nodes: Sequence[Node]) -> EngineResult:
-        t0 = time.perf_counter()
-        last_cycle = 0
-        K_WTT, K_DEV = self._KIND_WTT, self._KIND_DEV
-        cal: List[Tuple[int, int, int]] = []
-        push = heapq.heappush
-        pop = heapq.heappop
+        with span("engine.run") as run:
+            last_cycle = 0
+            K_WTT, K_DEV = self._KIND_WTT, self._KIND_DEV
+            cal: List[Tuple[int, int, int]] = []
+            push = heapq.heappush
+            pop = heapq.heappop
 
-        def push_dev(i: int, dev: TargetDevice) -> None:
-            c = dev.next_transition_cycle()
-            if c is not None:
-                push(cal, (c, K_DEV, i))
-
-        saved_hooks = [wtt.on_register for _, wtt in nodes]
-        try:
-            for i, (dev, wtt) in enumerate(nodes):
-                # every registration (seed traces were registered before the
-                # run; these are mid-run cross-device emissions) lands in the
-                # calendar the moment it happens
-                wtt.on_register = (
-                    lambda cyc, i=i: push(cal, (cyc, K_WTT, i))
-                )
-                c = wtt.peek_wakeup_cycle()
+            def push_dev(i: int, dev: TargetDevice) -> None:
+                c = dev.next_transition_cycle()
                 if c is not None:
-                    push(cal, (c, K_WTT, i))
-                push_dev(i, dev)
+                    push(cal, (c, K_DEV, i))
 
-            while True:
-                # earliest still-valid calendar entry (lazy invalidation:
-                # drained/deferred entries are dropped or re-timed on pop)
-                nxt = None
-                while cal:
-                    c, kind, i = cal[0]
-                    dev, wtt = nodes[i]
-                    cur = (
-                        wtt.peek_wakeup_cycle()
-                        if kind == K_WTT
-                        else dev.next_transition_cycle()
+            saved_hooks = [wtt.on_register for _, wtt in nodes]
+            try:
+                for i, (dev, wtt) in enumerate(nodes):
+                    # every registration (seed traces were registered before the
+                    # run; these are mid-run cross-device emissions) lands in the
+                    # calendar the moment it happens
+                    wtt.on_register = (
+                        lambda cyc, i=i: push(cal, (cyc, K_WTT, i))
                     )
-                    if cur != c:
-                        pop(cal)
-                        if cur is not None:
-                            push(cal, (cur, kind, i))
-                        continue
-                    nxt = c
-                    break
-                if nxt is None:
-                    if all(dev.all_done for dev, _ in nodes):
-                        break
-                    raise _deadlock_error(nodes, last_cycle)
-
-                # gather every node with an event at nxt (dedupe duplicates)
-                due_wtt: set = set()
-                due_dev: set = set()
-                while cal and cal[0][0] == nxt:
-                    _, kind, i = pop(cal)
-                    (due_wtt if kind == K_WTT else due_dev).add(i)
-                # writes enact before device transitions at equal cycles,
-                # devices in id order — matching the cycle engine's
-                # intra-cycle ordering
-                for i in sorted(due_wtt):
-                    dev, wtt = nodes[i]
-                    if wtt.peek_wakeup_cycle() != nxt:
-                        continue  # stale duplicate
-                    cycle, group = wtt.pop_next_group()
-                    for w in group:
-                        dev.memory.enact_xgmi_write(w, cycle)
-                    dev.on_writes_enacted(group, cycle)
                     c = wtt.peek_wakeup_cycle()
                     if c is not None:
                         push(cal, (c, K_WTT, i))
-                    due_dev.add(i)  # wakes may schedule transitions <= nxt
-                for i in sorted(due_dev):
-                    dev, _ = nodes[i]
-                    c = dev.next_transition_cycle()
-                    if c is not None and c <= nxt:
-                        dev.process_until(nxt)
                     push_dev(i, dev)
-                last_cycle = max(last_cycle, nxt)
-        finally:
-            for (_, wtt), hook in zip(nodes, saved_hooks):
-                wtt.on_register = hook
+
+                acted = 0  # calendar entries acted on: write groups + transitions
+                while True:
+                    # earliest still-valid calendar entry (lazy invalidation:
+                    # drained/deferred entries are dropped or re-timed on pop)
+                    nxt = None
+                    while cal:
+                        c, kind, i = cal[0]
+                        dev, wtt = nodes[i]
+                        cur = (
+                            wtt.peek_wakeup_cycle()
+                            if kind == K_WTT
+                            else dev.next_transition_cycle()
+                        )
+                        if cur != c:
+                            pop(cal)
+                            if cur is not None:
+                                push(cal, (cur, kind, i))
+                            continue
+                        nxt = c
+                        break
+                    if nxt is None:
+                        if all(dev.all_done for dev, _ in nodes):
+                            break
+                        raise _deadlock_error(nodes, last_cycle)
+
+                    # gather every node with an event at nxt (dedupe duplicates)
+                    due_wtt: set = set()
+                    due_dev: set = set()
+                    while cal and cal[0][0] == nxt:
+                        _, kind, i = pop(cal)
+                        (due_wtt if kind == K_WTT else due_dev).add(i)
+                    # writes enact before device transitions at equal cycles,
+                    # devices in id order — matching the cycle engine's
+                    # intra-cycle ordering
+                    for i in sorted(due_wtt):
+                        dev, wtt = nodes[i]
+                        if wtt.peek_wakeup_cycle() != nxt:
+                            continue  # stale duplicate
+                        acted += 1
+                        cycle, group = wtt.pop_next_group()
+                        for w in group:
+                            dev.memory.enact_xgmi_write(w, cycle)
+                        dev.on_writes_enacted(group, cycle)
+                        c = wtt.peek_wakeup_cycle()
+                        if c is not None:
+                            push(cal, (c, K_WTT, i))
+                        due_dev.add(i)  # wakes may schedule transitions <= nxt
+                    for i in sorted(due_dev):
+                        dev, _ = nodes[i]
+                        c = dev.next_transition_cycle()
+                        if c is not None and c <= nxt:
+                            acted += 1
+                            dev.process_until(nxt)
+                        push_dev(i, dev)
+                    last_cycle = max(last_cycle, nxt)
+            finally:
+                for (_, wtt), hook in zip(nodes, saved_hooks):
+                    wtt.on_register = hook
+        count("engine.events", acted)
         return EngineResult(
             sim_cycles=last_cycle,
-            wall_time_s=time.perf_counter() - t0,
+            wall_time_s=run.dur,
             head_polls=sum(wtt.stats.head_polls for _, wtt in nodes),
         )

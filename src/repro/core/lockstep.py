@@ -55,7 +55,6 @@ fallback into a hard error naming the reason.
 
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -72,6 +71,7 @@ from .scenario import (
     PhaseSpec,
     as_symbolic,
 )
+from .spans import span
 
 __all__ = [
     "LockstepEngine",
@@ -638,29 +638,27 @@ class LockstepEngine:
         (scenario, config, fabric) point — plans are read-only at run time,
         so a sweep revisiting the same shape skips recompilation.
         """
-        t0 = time.perf_counter()
-        if reuse is not None:
-            kind, plan = reuse
-            if kind == "tiered":
-                self._tiered = plan
+        with span("lockstep.compile") as compiling:
+            if reuse is not None:
+                kind, plan = reuse
+                if kind == "tiered":
+                    self._tiered = plan
+                else:
+                    self._plan = plan
             else:
-                self._plan = plan
-            self.breakdown["compile_s"] = time.perf_counter() - t0
-            self.breakdown["compile_cached"] = 1.0
-            return None
-        fab = self.cluster.fabric
-        try:
-            if fab.spec.name == "ring" and fab.n_nodes == 1:
-                self._plan = _compile(self.cluster)
-            else:
-                from .lockstep_tiered import compile_tiered
+                fab = self.cluster.fabric
+                try:
+                    if fab.spec.name == "ring" and fab.n_nodes == 1:
+                        self._plan = _compile(self.cluster)
+                    else:
+                        from .lockstep_tiered import compile_tiered
 
-                self._tiered = compile_tiered(self.cluster)
-        except UnsupportedProgram as e:
-            return str(e)
-        except ValueError as e:  # e.g. address-map probing out of range
-            return f"symbolic program probing failed: {e}"
-        self.breakdown["compile_s"] = time.perf_counter() - t0
+                        self._tiered = compile_tiered(self.cluster)
+                except UnsupportedProgram as e:
+                    return str(e)
+                except ValueError as e:  # e.g. address-map probing out of range
+                    return f"symbolic program probing failed: {e}"
+        self.breakdown["compile_s"] = compiling.dur
         return None
 
     def plan_handle(self):
@@ -677,249 +675,244 @@ class LockstepEngine:
             from .lockstep_tiered import run_tiered
 
             return run_tiered(self.cluster, self._tiered, self.breakdown)
-        t0 = time.perf_counter()
-        plan = self._plan
-        assert plan is not None, "compile() must succeed before run()"
-        cluster = self.cluster
-        cfg = cluster.cfg
-        n = cfg.n_devices
-        clock = cfg.clock_ghz
-        poll = cfg.poll_interval_cycles
-        check = cfg.flag_check_cycles
-        xgmi_lat = cfg.xgmi_enact_latency_ns
-        include_dw = cfg.include_data_writes
-        fab = cluster.fabric
-        bw, lat = fab._cls["ici"]
-        counts = plan.counts
-        total = plan.total
-        ar = np.arange(n, dtype=np.int64)
+        with span("lockstep.solve") as solve:
+            plan = self._plan
+            assert plan is not None, "compile() must succeed before run()"
+            cluster = self.cluster
+            cfg = cluster.cfg
+            n = cfg.n_devices
+            clock = cfg.clock_ghz
+            poll = cfg.poll_interval_cycles
+            check = cfg.flag_check_cycles
+            xgmi_lat = cfg.xgmi_enact_latency_ns
+            include_dw = cfg.include_data_writes
+            fab = cluster.fabric
+            bw, lat = fab._cls["ici"]
+            counts = plan.counts
+            total = plan.total
+            ar = np.arange(n, dtype=np.int64)
 
-        # cursor matrix: every rank starts its cohorts at the dispatch cycles
-        T = np.tile(plan.dispatch, (n, 1))
-        # per-rank traffic that varies by rank (spin reads); rank-uniform
-        # categories accumulate as plain ints
-        fr = np.zeros(n, np.int64)
-        rb = np.zeros(n, np.int64)
-        u_nfr = u_rb = u_lw = u_wb = u_xo = u_xob = 0
-        u_xi = u_xib = u_reg = u_marks = 0
-        # fabric state: the flat ring's ports are (rank, +-1); busy chains,
-        # port stats, and the used-port masks (only touched ports get busy
-        # entries written back, matching the engine's lazy dict)
-        busy = {
-            1: np.array(
-                [fab._busy_until_ns.get((r, 1), 0.0) for r in range(n)]
-            ),
-            -1: np.array(
-                [fab._busy_until_ns.get((r, -1), 0.0) for r in range(n)]
-            ),
-        }
-        used = {1: np.zeros(n, bool), -1: np.zeros(n, bool)}
-        pcnt = {1: np.zeros(n, np.int64), -1: np.zeros(n, np.int64)}
-        pbyt = {1: np.zeros(n, np.int64), -1: np.zeros(n, np.int64)}
-        pqd = {1: np.zeros(n), -1: np.zeros(n)}
-        g_msgs = 0
-        g_bytes = 0
-        g_q = 0.0
-        setcycs: Dict[int, np.ndarray] = {}
-        max_set = 0
-        seq_add = 0
+            # cursor matrix: every rank starts its cohorts at the dispatch cycles
+            T = np.tile(plan.dispatch, (n, 1))
+            # per-rank traffic that varies by rank (spin reads); rank-uniform
+            # categories accumulate as plain ints
+            fr = np.zeros(n, np.int64)
+            rb = np.zeros(n, np.int64)
+            u_nfr = u_rb = u_lw = u_wb = u_xo = u_xob = 0
+            u_xi = u_xib = u_reg = u_marks = 0
+            # fabric state: the flat ring's ports are (rank, +-1); busy chains,
+            # port stats, and the used-port masks (only touched ports get busy
+            # entries written back, matching the engine's lazy dict)
+            busy = {
+                1: np.array(
+                    [fab._busy_until_ns.get((r, 1), 0.0) for r in range(n)]
+                ),
+                -1: np.array(
+                    [fab._busy_until_ns.get((r, -1), 0.0) for r in range(n)]
+                ),
+            }
+            used = {1: np.zeros(n, bool), -1: np.zeros(n, bool)}
+            pcnt = {1: np.zeros(n, np.int64), -1: np.zeros(n, np.int64)}
+            pbyt = {1: np.zeros(n, np.int64), -1: np.zeros(n, np.int64)}
+            pqd = {1: np.zeros(n), -1: np.zeros(n)}
+            g_msgs = 0
+            g_bytes = 0
+            g_q = 0.0
+            setcycs: Dict[int, np.ndarray] = {}
+            max_set = 0
+            seq_add = 0
 
-        def spin(V):
-            """One wait address against the cursor matrix: the interpreter's
-            unified closed form, vectorized over ranks x cohorts."""
-            nonlocal fr, rb, T
-            nt = V[:, None] - T
-            nt += poll - 1
-            nt //= poll
-            np.maximum(nt, 0, out=nt)
-            m = nt @ counts
-            m += total
-            fr += m
-            rb += 8 * m
-            nt *= poll
-            nt += check
-            T += nt
+            def spin(V):
+                """One wait address against the cursor matrix: the interpreter's
+                unified closed form, vectorized over ranks x cohorts."""
+                nonlocal fr, rb, T
+                nt = V[:, None] - T
+                nt += poll - 1
+                nt //= poll
+                np.maximum(nt, 0, out=nt)
+                m = nt @ counts
+                m += total
+                fr += m
+                rb += 8 * m
+                nt *= poll
+                nt += check
+                T += nt
 
-        stage_id = 0
-        for seg in plan.segs:
-            for k in range(seg.k0, seg.k0 + seg.count):
-                for pp in seg.body:
-                    if pp.is_wait:
-                        src = plan.wait_src[stage_id]
-                        if src[0] == "single":
-                            sc = setcycs.pop(src[1])
-                            spin(sc[src[2]])
+            stage_id = 0
+            for seg in plan.segs:
+                for k in range(seg.k0, seg.k0 + seg.count):
+                    for pp in seg.body:
+                        if pp.is_wait:
+                            src = plan.wait_src[stage_id]
+                            if src[0] == "single":
+                                sc = setcycs.pop(src[1])
+                                spin(sc[src[2]])
+                            else:
+                                M = setcycs.pop(src[1])
+                                for j in range(n - 1):
+                                    g = np.where(ar > j, j, j + 1)
+                                    spin(M[g, ar])
                         else:
-                            M = setcycs.pop(src[1])
-                            for j in range(n - 1):
-                                g = np.where(ar > j, j, j + 1)
-                                spin(M[g, ar])
-                    else:
-                        if pp.dur:
-                            T += pp.dur
-                        e = pp.emit
-                        if e is not None:
-                            E = T.max(axis=1)
-                            issue = E / clock
-                            nb = e.payload + e.size
-                            dw = e.dw if include_dw and e.dw > 0 else 0
-                            regs = 1 + dw
-                            if isinstance(e, _SingleEmit):
-                                ser = nb / bw
-                                dstv = e.dst_base + e.dst_step * k
-                                off = (dstv - ar) % n
-                                hops = np.minimum(off, n - off)
-                                dirs = np.where(2 * off <= n, 1, -1)
-                                arrns = np.empty(n)
-                                for dval in (1, -1):
-                                    msk = dirs == dval
-                                    if not msk.any():
-                                        continue
-                                    b = busy[dval]
-                                    st = np.maximum(issue[msk], b[msk])
-                                    nbsy = st + ser
-                                    b[msk] = nbsy
-                                    used[dval][msk] = True
-                                    q = st - issue[msk]
-                                    arrns[msk] = nbsy + hops[msk] * lat
-                                    pcnt[dval][msk] += 1
-                                    pbyt[dval][msk] += nb
-                                    pqd[dval][msk] += q
-                                    g_q += float(np.cumsum(q)[-1])
-                                g_msgs += n
-                                g_bytes += n * nb
-                                wake = arrns + xgmi_lat
-                                minns = (E + 1) / clock
-                                np.maximum(wake, minns, out=wake)
-                                sc = np.rint(wake * clock).astype(np.int64)
-                                setcycs[stage_id] = sc
-                                ms = int(sc.max())
-                                if ms > max_set:
-                                    max_set = ms
-                                u_xo += 1
-                                u_xob += e.size
-                                u_xi += regs
-                                u_xib += e.size + 8 * dw
-                                u_reg += regs
-                                u_marks += dw
-                                seq_add += n * regs
-                            else:  # _FanoutEmit
-                                M = np.zeros((n, n), np.int64)
-                                for r in range(n):
-                                    iss = float(E[r]) / clock
-                                    ds = np.concatenate(
-                                        (ar[:r], ar[r + 1:])
-                                    )
-                                    off = (ds - r) % n
+                            if pp.dur:
+                                T += pp.dur
+                            e = pp.emit
+                            if e is not None:
+                                E = T.max(axis=1)
+                                issue = E / clock
+                                nb = e.payload + e.size
+                                dw = e.dw if include_dw and e.dw > 0 else 0
+                                regs = 1 + dw
+                                if isinstance(e, _SingleEmit):
+                                    ser = nb / bw
+                                    dstv = e.dst_base + e.dst_step * k
+                                    off = (dstv - ar) % n
                                     hops = np.minimum(off, n - off)
-                                    pos = 2 * off <= n
-                                    minns = (float(E[r]) + 1.0) / clock
-                                    for dval, msk in ((1, pos), (-1, ~pos)):
-                                        cnt = int(msk.sum())
-                                        if not cnt:
+                                    dirs = np.where(2 * off <= n, 1, -1)
+                                    arrns = np.empty(n)
+                                    for dval in (1, -1):
+                                        msk = dirs == dval
+                                        if not msk.any():
                                             continue
-                                        b0 = float(busy[dval][r])
-                                        start0 = max(iss, b0)
-                                        # the exact per-port cumsum chain of
-                                        # FabricModel.transfer_batch
-                                        chain = np.empty(cnt + 1)
-                                        chain[0] = start0
-                                        chain[1:] = nb / bw
-                                        bs = np.cumsum(chain)
-                                        busy[dval][r] = float(bs[-1])
-                                        used[dval][r] = True
-                                        arrm = bs[1:] + hops[msk] * lat
-                                        q = bs[:-1] - iss
-                                        pcnt[dval][r] += cnt
-                                        pbyt[dval][r] += cnt * nb
-                                        pqd[dval][r] += float(
-                                            np.cumsum(q)[-1]
-                                        )
+                                        b = busy[dval]
+                                        st = np.maximum(issue[msk], b[msk])
+                                        nbsy = st + ser
+                                        b[msk] = nbsy
+                                        used[dval][msk] = True
+                                        q = st - issue[msk]
+                                        arrns[msk] = nbsy + hops[msk] * lat
+                                        pcnt[dval][msk] += 1
+                                        pbyt[dval][msk] += nb
+                                        pqd[dval][msk] += q
                                         g_q += float(np.cumsum(q)[-1])
-                                        wake = arrm + xgmi_lat
-                                        np.maximum(wake, minns, out=wake)
-                                        M[r, ds[msk]] = np.rint(
-                                            wake * clock
-                                        ).astype(np.int64)
-                                setcycs[stage_id] = M
-                                ms = int(M.max())
-                                if ms > max_set:
-                                    max_set = ms
-                                g_msgs += n * (n - 1)
-                                g_bytes += n * (n - 1) * nb
-                                u_xo += n - 1
-                                u_xob += (n - 1) * e.size
-                                u_xi += (n - 1) * regs
-                                u_xib += (n - 1) * (e.size + 8 * dw)
-                                u_reg += (n - 1) * regs
-                                u_marks += (n - 1) * dw
-                                seq_add += n * (n - 1) * regs
-                    d = pp.tdelta
-                    if d is not None:
-                        u_nfr += d[0] * total
-                        u_rb += d[1] * total
-                        u_lw += d[2] * total
-                        u_wb += d[3] * total
-                        u_xo += d[4] * total
-                        u_xob += d[5] * total
-                    stage_id += 1
-
-        solve_done = time.perf_counter()
+                                    g_msgs += n
+                                    g_bytes += n * nb
+                                    wake = arrns + xgmi_lat
+                                    minns = (E + 1) / clock
+                                    np.maximum(wake, minns, out=wake)
+                                    sc = np.rint(wake * clock).astype(np.int64)
+                                    setcycs[stage_id] = sc
+                                    ms = int(sc.max())
+                                    if ms > max_set:
+                                        max_set = ms
+                                    u_xo += 1
+                                    u_xob += e.size
+                                    u_xi += regs
+                                    u_xib += e.size + 8 * dw
+                                    u_reg += regs
+                                    u_marks += dw
+                                    seq_add += n * regs
+                                else:  # _FanoutEmit
+                                    M = np.zeros((n, n), np.int64)
+                                    for r in range(n):
+                                        iss = float(E[r]) / clock
+                                        ds = np.concatenate(
+                                            (ar[:r], ar[r + 1:])
+                                        )
+                                        off = (ds - r) % n
+                                        hops = np.minimum(off, n - off)
+                                        pos = 2 * off <= n
+                                        minns = (float(E[r]) + 1.0) / clock
+                                        for dval, msk in ((1, pos), (-1, ~pos)):
+                                            cnt = int(msk.sum())
+                                            if not cnt:
+                                                continue
+                                            b0 = float(busy[dval][r])
+                                            start0 = max(iss, b0)
+                                            # the exact per-port cumsum chain of
+                                            # FabricModel.transfer_batch
+                                            chain = np.empty(cnt + 1)
+                                            chain[0] = start0
+                                            chain[1:] = nb / bw
+                                            bs = np.cumsum(chain)
+                                            busy[dval][r] = float(bs[-1])
+                                            used[dval][r] = True
+                                            arrm = bs[1:] + hops[msk] * lat
+                                            q = bs[:-1] - iss
+                                            pcnt[dval][r] += cnt
+                                            pbyt[dval][r] += cnt * nb
+                                            pqd[dval][r] += float(
+                                                np.cumsum(q)[-1]
+                                            )
+                                            g_q += float(np.cumsum(q)[-1])
+                                            wake = arrm + xgmi_lat
+                                            np.maximum(wake, minns, out=wake)
+                                            M[r, ds[msk]] = np.rint(
+                                                wake * clock
+                                            ).astype(np.int64)
+                                    setcycs[stage_id] = M
+                                    ms = int(M.max())
+                                    if ms > max_set:
+                                        max_set = ms
+                                    g_msgs += n * (n - 1)
+                                    g_bytes += n * (n - 1) * nb
+                                    u_xo += n - 1
+                                    u_xob += (n - 1) * e.size
+                                    u_xi += (n - 1) * regs
+                                    u_xib += (n - 1) * (e.size + 8 * dw)
+                                    u_reg += (n - 1) * regs
+                                    u_marks += (n - 1) * dw
+                                    seq_add += n * (n - 1) * regs
+                        d = pp.tdelta
+                        if d is not None:
+                            u_nfr += d[0] * total
+                            u_rb += d[1] * total
+                            u_lw += d[2] * total
+                            u_wb += d[3] * total
+                            u_xo += d[4] * total
+                            u_xob += d[5] * total
+                        stage_id += 1
 
         # ---- write-back -------------------------------------------------
-        kend = T.max(axis=1)
-        sim_cycles = max(int(kend.max()), max_set)
-        for r, node in enumerate(self.cluster.nodes):
-            t = node.memory.traffic
-            t.flag_reads += int(fr[r])
-            t.nonflag_reads += u_nfr
-            t.read_bytes += int(rb[r]) + u_rb
-            t.local_writes += u_lw
-            t.write_bytes += u_wb
-            t.xgmi_writes_out += u_xo
-            t.xgmi_bytes_out += u_xob
-            t.xgmi_writes_in += u_xi
-            t.xgmi_bytes_in += u_xib
-            tgt = node.target
-            tgt.done_count = tgt.n_wgs
-            tgt.kernel_end_cycle = int(kend[r])
-            ws = node.wtt.stats
-            ws.registered += u_reg
-            ws.enacted += u_reg
-            if u_marks:
-                cluster._data_marks[r] = (
-                    cluster._data_marks.get(r, 0) + u_marks
-                )
-        cluster._seq += seq_add
-        st = fab.stats
-        st["messages"] += g_msgs
-        st["bytes"] += g_bytes
-        st["queued_ns"] += g_q
-        st["ici_messages"] += g_msgs
-        st["ici_bytes"] += g_bytes
-        st["ici_queued_ns"] += g_q
-        for dval in (1, -1):
-            um = used[dval]
-            for r in np.flatnonzero(um):
-                r = int(r)
-                port = (r, dval)
-                fab._busy_until_ns[port] = float(busy[dval][r])
-                ps = fab.port_stats.get(port)
-                if ps is None:
-                    ps = fab.port_stats[port] = [0, 0, 0.0]
-                ps[0] += int(pcnt[dval][r])
-                ps[1] += int(pbyt[dval][r])
-                ps[2] += float(pqd[dval][r])
-        run_wall = time.perf_counter() - t0
-        self.breakdown.update(
-            solve_s=solve_done - t0,
-            writeback_s=run_wall - (solve_done - t0),
-        )
+        with span("lockstep.writeback") as writeback:
+            kend = T.max(axis=1)
+            sim_cycles = max(int(kend.max()), max_set)
+            for r, node in enumerate(self.cluster.nodes):
+                t = node.memory.traffic
+                t.flag_reads += int(fr[r])
+                t.nonflag_reads += u_nfr
+                t.read_bytes += int(rb[r]) + u_rb
+                t.local_writes += u_lw
+                t.write_bytes += u_wb
+                t.xgmi_writes_out += u_xo
+                t.xgmi_bytes_out += u_xob
+                t.xgmi_writes_in += u_xi
+                t.xgmi_bytes_in += u_xib
+                tgt = node.target
+                tgt.done_count = tgt.n_wgs
+                tgt.kernel_end_cycle = int(kend[r])
+                ws = node.wtt.stats
+                ws.registered += u_reg
+                ws.enacted += u_reg
+                if u_marks:
+                    cluster._data_marks[r] = (
+                        cluster._data_marks.get(r, 0) + u_marks
+                    )
+            cluster._seq += seq_add
+            st = fab.stats
+            st["messages"] += g_msgs
+            st["bytes"] += g_bytes
+            st["queued_ns"] += g_q
+            st["ici_messages"] += g_msgs
+            st["ici_bytes"] += g_bytes
+            st["ici_queued_ns"] += g_q
+            for dval in (1, -1):
+                um = used[dval]
+                for r in np.flatnonzero(um):
+                    r = int(r)
+                    port = (r, dval)
+                    fab._busy_until_ns[port] = float(busy[dval][r])
+                    ps = fab.port_stats.get(port)
+                    if ps is None:
+                        ps = fab.port_stats[port] = [0, 0, 0.0]
+                    ps[0] += int(pcnt[dval][r])
+                    ps[1] += int(pbyt[dval][r])
+                    ps[2] += float(pqd[dval][r])
+        self.breakdown.update(solve_s=solve.dur, writeback_s=writeback.dur)
         return EngineResult(
             sim_cycles=sim_cycles,
-            # the compile pass is part of this engine's cost; include it so
-            # wall_time_s >= sum(breakdown.values())
-            wall_time_s=run_wall + self.breakdown.get("compile_s", 0.0),
+            # the compile pass is part of this engine's cost
+            wall_time_s=solve.dur + writeback.dur
+            + self.breakdown.get("compile_s", 0.0),
             head_polls=0,
             breakdown=self.breakdown,
         )

@@ -46,7 +46,6 @@ every integer counter stay bit-identical.
 
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -61,6 +60,7 @@ from .scenario import (
     LoopSpec,
     as_symbolic,
 )
+from .spans import span
 
 __all__ = ["compile_tiered", "run_tiered"]
 
@@ -1222,376 +1222,371 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
     """Solve the compiled tiered plan; mutates cluster state only in the
     final write-back (a mid-solve failure falls back to the timeline engine
     cleanly)."""
-    t0 = time.perf_counter()
-    cfg = cluster.cfg
-    n = cfg.n_devices
-    clock = cfg.clock_ghz
-    poll = cfg.poll_interval_cycles
-    check = cfg.flag_check_cycles
-    xgmi_lat = cfg.xgmi_enact_latency_ns
-    include_dw = cfg.include_data_writes
-    fab = cluster.fabric
-    ports = plan.ports
-    groups = plan.groups
-    ar_n = np.arange(n, dtype=np.int64)
+    with span("lockstep.solve") as solve:
+        cfg = cluster.cfg
+        n = cfg.n_devices
+        clock = cfg.clock_ghz
+        poll = cfg.poll_interval_cycles
+        check = cfg.flag_check_cycles
+        xgmi_lat = cfg.xgmi_enact_latency_ns
+        include_dw = cfg.include_data_writes
+        fab = cluster.fabric
+        ports = plan.ports
+        groups = plan.groups
+        ar_n = np.arange(n, dtype=np.int64)
 
-    P = ports.P
-    port_busy = np.array(
-        [fab._busy_until_ns.get(t, 0.0) for t in ports.tuples]
-    )
-    port_used = np.zeros(P, bool)
-    port_cnt = np.zeros(P, np.int64)
-    port_byt = np.zeros(P, np.int64)
-    port_qd = np.zeros(P)
-    port_bw = ports.cls_bw[ports.port_cls]
-    port_lat = ports.cls_lat[ports.port_cls]
-    C = len(ports.cls_names)
-    cls_msgs = np.zeros(C, np.int64)
-    cls_bytes = np.zeros(C, np.int64)
-    cls_q = np.zeros(C)
-    g_msgs = 0
-    g_bytes = 0
-    g_q = 0.0
-    seq_add = 0
-    max_set = 0
+        P = ports.P
+        port_busy = np.array(
+            [fab._busy_until_ns.get(t, 0.0) for t in ports.tuples]
+        )
+        port_used = np.zeros(P, bool)
+        port_cnt = np.zeros(P, np.int64)
+        port_byt = np.zeros(P, np.int64)
+        port_qd = np.zeros(P)
+        port_bw = ports.cls_bw[ports.port_cls]
+        port_lat = ports.cls_lat[ports.port_cls]
+        C = len(ports.cls_names)
+        cls_msgs = np.zeros(C, np.int64)
+        cls_bytes = np.zeros(C, np.int64)
+        cls_q = np.zeros(C)
+        g_msgs = 0
+        g_bytes = 0
+        g_q = 0.0
+        seq_add = 0
+        max_set = 0
 
-    a_fr = np.zeros(n, np.int64)
-    a_rb = np.zeros(n, np.int64)
-    a_nfr = np.zeros(n, np.int64)
-    a_lw = np.zeros(n, np.int64)
-    a_wb = np.zeros(n, np.int64)
-    a_xo = np.zeros(n, np.int64)
-    a_xob = np.zeros(n, np.int64)
-    a_xi = np.zeros(n, np.int64)
-    a_xib = np.zeros(n, np.int64)
-    a_reg = np.zeros(n, np.int64)
-    a_marks = np.zeros(n, np.int64)
+        a_fr = np.zeros(n, np.int64)
+        a_rb = np.zeros(n, np.int64)
+        a_nfr = np.zeros(n, np.int64)
+        a_lw = np.zeros(n, np.int64)
+        a_wb = np.zeros(n, np.int64)
+        a_xo = np.zeros(n, np.int64)
+        a_xob = np.zeros(n, np.int64)
+        a_xi = np.zeros(n, np.int64)
+        a_xib = np.zeros(n, np.int64)
+        a_reg = np.zeros(n, np.int64)
+        a_marks = np.zeros(n, np.int64)
 
-    T = [np.tile(g.dispatch, (len(g.devs), 1)) for g in groups]
-    sc_store: Dict[int, np.ndarray] = {}
-    refs = plan.refs.copy()
+        T = [np.tile(g.dispatch, (len(g.devs), 1)) for g in groups]
+        sc_store: Dict[int, np.ndarray] = {}
+        refs = plan.refs.copy()
 
-    def spin(gi, V):
-        """The interpreter's unified spin closed form over one group's
-        cursor matrix (one wait address per rank)."""
-        grp = groups[gi]
-        nt = V[:, None] - T[gi]
-        nt += poll - 1
-        nt //= poll
-        np.maximum(nt, 0, out=nt)
-        m = nt @ grp.counts
-        m += grp.total
-        a_fr[grp.devs] += m
-        a_rb[grp.devs] += 8 * m
-        nt *= poll
-        nt += check
-        T[gi] += nt
+        def spin(gi, V):
+            """The interpreter's unified spin closed form over one group's
+            cursor matrix (one wait address per rank)."""
+            grp = groups[gi]
+            nt = V[:, None] - T[gi]
+            nt += poll - 1
+            nt //= poll
+            np.maximum(nt, 0, out=nt)
+            m = nt @ grp.counts
+            m += grp.total
+            a_fr[grp.devs] += m
+            a_rb[grp.devs] += 8 * m
+            nt *= poll
+            nt += check
+            T[gi] += nt
 
-    def tdapply(gi, d):
-        if d is None:
-            return
-        grp = groups[gi]
-        tot = grp.total
-        devs = grp.devs
-        if d[0]:
-            a_nfr[devs] += d[0] * tot
-        if d[1]:
-            a_rb[devs] += d[1] * tot
-        if d[2]:
-            a_lw[devs] += d[2] * tot
-        if d[3]:
-            a_wb[devs] += d[3] * tot
-        if d[4]:
-            a_xo[devs] += d[4] * tot
-        if d[5]:
-            a_xob[devs] += d[5] * tot
+        def tdapply(gi, d):
+            if d is None:
+                return
+            grp = groups[gi]
+            tot = grp.total
+            devs = grp.devs
+            if d[0]:
+                a_nfr[devs] += d[0] * tot
+            if d[1]:
+                a_rb[devs] += d[1] * tot
+            if d[2]:
+                a_lw[devs] += d[2] * tot
+            if d[3]:
+                a_wb[devs] += d[3] * tot
+            if d[4]:
+                a_xo[devs] += d[4] * tot
+            if d[5]:
+                a_xob[devs] += d[5] * tot
 
-    def price_elem(fam, issue):
-        """Leg-by-leg elementwise pricing; valid because no two messages of
-        the instance share a port (checked at compile)."""
-        nonlocal g_q
-        nb = fam.nb
-        arr = issue.copy()
-        for mi, prt, hops, cls in fam.leg_slots:
-            rdy = arr[mi]
-            st = np.maximum(rdy, port_busy[prt])
-            ser = nb / port_bw[prt]
-            fin = st + ser
-            port_busy[prt] = fin
-            port_used[prt] = True
-            q = st - rdy
-            port_cnt[prt] += 1
-            port_byt[prt] += nb
-            port_qd[prt] += q
-            g_q += float(q.sum())
-            np.add.at(cls_q, cls, q)
-            arr[mi] = fin + hops * port_lat[prt]
-        return arr
+        def price_elem(fam, issue):
+            """Leg-by-leg elementwise pricing; valid because no two messages of
+            the instance share a port (checked at compile)."""
+            nonlocal g_q
+            nb = fam.nb
+            arr = issue.copy()
+            for mi, prt, hops, cls in fam.leg_slots:
+                rdy = arr[mi]
+                st = np.maximum(rdy, port_busy[prt])
+                ser = nb / port_bw[prt]
+                fin = st + ser
+                port_busy[prt] = fin
+                port_used[prt] = True
+                q = st - rdy
+                port_cnt[prt] += 1
+                port_byt[prt] += nb
+                port_qd[prt] += q
+                g_q += float(q.sum())
+                np.add.at(cls_q, cls, q)
+                arr[mi] = fin + hops * port_lat[prt]
+            return arr
 
-    def price_ordered(fam, issue, E_msg, legs):
-        """Port-wavefront pricing in the event engine's global message
-        order; each sweep extends every port's priced prefix with the
-        touches whose upstream legs have resolved arrivals."""
-        nonlocal g_q
-        nb = fam.nb
-        m = len(issue)
-        msg = legs["msg"]
-        L = len(msg)
-        if np.all(E_msg == E_msg[0]):
-            tmsg = msg
-            tprt = legs["port"]
-            thops = legs["hops"]
-        else:
-            morder = np.argsort(E_msg, kind="stable")
-            inv = np.empty(m, np.int64)
-            inv[morder] = np.arange(m, dtype=np.int64)
-            tord = np.lexsort((np.arange(L), inv[msg]))
-            tmsg = msg[tord]
-            tprt = legs["port"][tord]
-            thops = legs["hops"][tord]
-        first = np.ones(L, bool)
-        first[1:] = tmsg[1:] != tmsg[:-1]
-        ready = np.full(L, np.nan)
-        ready[first] = issue[tmsg[first]]
-        nxt = np.full(L, -1, np.int32)
-        cont = np.flatnonzero(~first[1:])
-        nxt[cont] = cont + 1
-        last = np.ones(L, bool)
-        last[:-1] = first[1:]
-        tsort = np.argsort(tprt, kind="stable")
-        # tsort groups legs by ascending port id; per-port extents come from
-        # a bincount (no gather of the sorted keys, no diff pass)
-        pcnt = np.bincount(tprt, minlength=ports.P)
-        plist = np.flatnonzero(pcnt)
-        pend = np.cumsum(pcnt[plist])
-        pstart = pend - pcnt[plist]
-        cursor = np.zeros(len(plist), np.int64)
-        arr_out = np.empty(m)
-        done = 0
-        while done < L:
-            moved = False
-            for pi in range(len(plist)):
-                s = int(pstart[pi] + cursor[pi])
-                e = int(pend[pi])
-                if s >= e:
-                    continue
-                tl = tsort[s:e]
-                rdy = ready[tl]
-                isn = np.isnan(rdy)
-                cnt = int(isn.argmax())
-                if cnt == 0:
-                    if isn[0]:
+        def price_ordered(fam, issue, E_msg, legs):
+            """Port-wavefront pricing in the event engine's global message
+            order; each sweep extends every port's priced prefix with the
+            touches whose upstream legs have resolved arrivals."""
+            nonlocal g_q
+            nb = fam.nb
+            m = len(issue)
+            msg = legs["msg"]
+            L = len(msg)
+            if np.all(E_msg == E_msg[0]):
+                tmsg = msg
+                tprt = legs["port"]
+                thops = legs["hops"]
+            else:
+                morder = np.argsort(E_msg, kind="stable")
+                inv = np.empty(m, np.int64)
+                inv[morder] = np.arange(m, dtype=np.int64)
+                tord = np.lexsort((np.arange(L), inv[msg]))
+                tmsg = msg[tord]
+                tprt = legs["port"][tord]
+                thops = legs["hops"][tord]
+            first = np.ones(L, bool)
+            first[1:] = tmsg[1:] != tmsg[:-1]
+            ready = np.full(L, np.nan)
+            ready[first] = issue[tmsg[first]]
+            nxt = np.full(L, -1, np.int32)
+            cont = np.flatnonzero(~first[1:])
+            nxt[cont] = cont + 1
+            last = np.ones(L, bool)
+            last[:-1] = first[1:]
+            tsort = np.argsort(tprt, kind="stable")
+            # tsort groups legs by ascending port id; per-port extents come from
+            # a bincount (no gather of the sorted keys, no diff pass)
+            pcnt = np.bincount(tprt, minlength=ports.P)
+            plist = np.flatnonzero(pcnt)
+            pend = np.cumsum(pcnt[plist])
+            pstart = pend - pcnt[plist]
+            cursor = np.zeros(len(plist), np.int64)
+            arr_out = np.empty(m)
+            done = 0
+            while done < L:
+                moved = False
+                for pi in range(len(plist)):
+                    s = int(pstart[pi] + cursor[pi])
+                    e = int(pend[pi])
+                    if s >= e:
                         continue
-                    cnt = len(tl)
-                tl = tl[:cnt]
-                rdy = rdy[:cnt]
-                p = int(plist[pi])
-                ser = nb / port_bw[p]
-                sts, bfin = _chain(port_busy[p], rdy, ser)
-                port_busy[p] = bfin
-                port_used[p] = True
-                fin = sts + ser
-                q = sts - rdy
-                port_cnt[p] += cnt
-                port_byt[p] += cnt * nb
-                port_qd[p] = float(
-                    np.cumsum(np.concatenate(([port_qd[p]], q)))[-1]
-                )
-                qs = float(q.sum())
-                g_q += qs
-                cls_q[ports.port_cls[p]] += qs
-                a = fin + thops[tl] * port_lat[p]
-                nx = nxt[tl]
-                has = nx >= 0
-                ready[nx[has]] = a[has]
-                lm = last[tl]
-                arr_out[tmsg[tl[lm]]] = a[lm]
-                cursor[pi] += cnt
-                done += cnt
-                moved = True
-            if not moved:  # pragma: no cover - leg classes form a DAG
-                raise _unsupported(
-                    "link-port pricing stalled (non-DAG port order)"
-                )
-        return arr_out
+                    tl = tsort[s:e]
+                    rdy = ready[tl]
+                    isn = np.isnan(rdy)
+                    cnt = int(isn.argmax())
+                    if cnt == 0:
+                        if isn[0]:
+                            continue
+                        cnt = len(tl)
+                    tl = tl[:cnt]
+                    rdy = rdy[:cnt]
+                    p = int(plist[pi])
+                    ser = nb / port_bw[p]
+                    sts, bfin = _chain(port_busy[p], rdy, ser)
+                    port_busy[p] = bfin
+                    port_used[p] = True
+                    fin = sts + ser
+                    q = sts - rdy
+                    port_cnt[p] += cnt
+                    port_byt[p] += cnt * nb
+                    port_qd[p] = float(
+                        np.cumsum(np.concatenate(([port_qd[p]], q)))[-1]
+                    )
+                    qs = float(q.sum())
+                    g_q += qs
+                    cls_q[ports.port_cls[p]] += qs
+                    a = fin + thops[tl] * port_lat[p]
+                    nx = nxt[tl]
+                    has = nx >= 0
+                    ready[nx[has]] = a[has]
+                    lm = last[tl]
+                    arr_out[tmsg[tl[lm]]] = a[lm]
+                    cursor[pi] += cnt
+                    done += cnt
+                    moved = True
+                if not moved:  # pragma: no cover - leg classes form a DAG
+                    raise _unsupported(
+                        "link-port pricing stalled (non-DAG port order)"
+                    )
+            return arr_out
 
-    def account(fam, nmsg_per_rank, devs):
-        nonlocal seq_add, g_msgs, g_bytes
-        nonlocal a_xi, a_xib, a_reg, a_marks
-        dw = fam.dw if include_dw and fam.dw > 0 else 0
-        regs = 1 + dw
-        a_xo[devs] += nmsg_per_rank
-        a_xob[devs] += nmsg_per_rank * fam.size
-        if fam.kind == "fanout_all":
-            a_xi += nmsg_per_rank * regs
-            a_xib += nmsg_per_rank * (fam.size + 8 * dw)
-            a_reg += nmsg_per_rank * regs
-            if dw:
-                a_marks += nmsg_per_rank * dw
-        elif fam.dst_unique:
-            a_xi[fam.dst] += regs
-            a_xib[fam.dst] += fam.size + 8 * dw
-            a_reg[fam.dst] += regs
-            if dw:
-                a_marks[fam.dst] += dw
-        else:
-            np.add.at(a_xi, fam.dst, regs)
-            np.add.at(a_xib, fam.dst, fam.size + 8 * dw)
-            np.add.at(a_reg, fam.dst, regs)
-            if dw:
-                np.add.at(a_marks, fam.dst, dw)
-        seq_add += fam.m * regs
-        g_msgs += fam.m
-        g_bytes += fam.m * fam.nb
+        def account(fam, nmsg_per_rank, devs):
+            nonlocal seq_add, g_msgs, g_bytes
+            nonlocal a_xi, a_xib, a_reg, a_marks
+            dw = fam.dw if include_dw and fam.dw > 0 else 0
+            regs = 1 + dw
+            a_xo[devs] += nmsg_per_rank
+            a_xob[devs] += nmsg_per_rank * fam.size
+            if fam.kind == "fanout_all":
+                a_xi += nmsg_per_rank * regs
+                a_xib += nmsg_per_rank * (fam.size + 8 * dw)
+                a_reg += nmsg_per_rank * regs
+                if dw:
+                    a_marks += nmsg_per_rank * dw
+            elif fam.dst_unique:
+                a_xi[fam.dst] += regs
+                a_xib[fam.dst] += fam.size + 8 * dw
+                a_reg[fam.dst] += regs
+                if dw:
+                    a_marks[fam.dst] += dw
+            else:
+                np.add.at(a_xi, fam.dst, regs)
+                np.add.at(a_xib, fam.dst, fam.size + 8 * dw)
+                np.add.at(a_reg, fam.dst, regs)
+                if dw:
+                    np.add.at(a_marks, fam.dst, dw)
+            seq_add += fam.m * regs
+            g_msgs += fam.m
+            g_bytes += fam.m * fam.nb
 
-    def emit_family(fam, uid):
-        nonlocal max_set, cls_msgs, cls_bytes
-        gi = fam.gi
-        grp = groups[gi]
-        E = T[gi].max(axis=1)
-        issue_r = E / clock
-        minns_r = (E + 1) / clock
-        issue = issue_r[fam.src_row]
-        if fam.pricing == "elem":
-            arr = price_elem(fam, issue)
-        else:
-            arr = price_ordered(fam, issue, E[fam.src_row], fam.legs)
-        wake = arr + xgmi_lat
-        np.maximum(wake, minns_r[fam.src_row], out=wake)
-        sc = np.rint(wake * clock).astype(np.int64)
-        ms = int(sc.max())
-        if ms > max_set:
-            max_set = ms
-        if refs[uid] > 0:
-            sc_store[uid] = sc
-        account(fam, fam.cnt, grp.devs)
-        cls_msgs += fam.cls_legs
-        cls_bytes += fam.cls_legs * fam.nb
+        def emit_family(fam, uid):
+            nonlocal max_set, cls_msgs, cls_bytes
+            gi = fam.gi
+            grp = groups[gi]
+            E = T[gi].max(axis=1)
+            issue_r = E / clock
+            minns_r = (E + 1) / clock
+            issue = issue_r[fam.src_row]
+            if fam.pricing == "elem":
+                arr = price_elem(fam, issue)
+            else:
+                arr = price_ordered(fam, issue, E[fam.src_row], fam.legs)
+            wake = arr + xgmi_lat
+            np.maximum(wake, minns_r[fam.src_row], out=wake)
+            sc = np.rint(wake * clock).astype(np.int64)
+            ms = int(sc.max())
+            if ms > max_set:
+                max_set = ms
+            if refs[uid] > 0:
+                sc_store[uid] = sc
+            account(fam, fam.cnt, grp.devs)
+            cls_msgs += fam.cls_legs
+            cls_bytes += fam.cls_legs * fam.nb
 
-    def emit_fanout(fam, uid):
-        nonlocal max_set, cls_msgs, cls_bytes
-        gi = fam.gi
-        E = T[gi].max(axis=1)
-        src = np.repeat(np.arange(n, dtype=np.int32), n - 1)
-        dstm = np.tile(np.arange(n - 1, dtype=np.int32), (n, 1))
-        dstm += dstm >= ar_n[:, None]
-        dst = dstm.ravel()
-        legs = _legs_csr(ports, src, dst)
-        _spot_check(ports, fab, src, dst, legs)
-        issue = (E / clock)[src]
-        arr = price_ordered(fam, issue, E[src], legs)
-        minns = ((E + 1) / clock)[src]
-        wake = arr + xgmi_lat
-        np.maximum(wake, minns, out=wake)
-        sc = np.rint(wake * clock).astype(np.int64)
-        ms = int(sc.max())
-        if ms > max_set:
-            max_set = ms
-        if refs[uid] > 0:
-            M = np.zeros((n, n), np.int64)
-            M[src, dst] = sc
-            sc_store[uid] = M
-        account(fam, n - 1, ar_n)
-        cls_msgs += np.bincount(legs["cls"], minlength=C)
-        cls_bytes += np.bincount(legs["cls"], minlength=C) * fam.nb
+        def emit_fanout(fam, uid):
+            nonlocal max_set, cls_msgs, cls_bytes
+            gi = fam.gi
+            E = T[gi].max(axis=1)
+            src = np.repeat(np.arange(n, dtype=np.int32), n - 1)
+            dstm = np.tile(np.arange(n - 1, dtype=np.int32), (n, 1))
+            dstm += dstm >= ar_n[:, None]
+            dst = dstm.ravel()
+            legs = _legs_csr(ports, src, dst)
+            _spot_check(ports, fab, src, dst, legs)
+            issue = (E / clock)[src]
+            arr = price_ordered(fam, issue, E[src], legs)
+            minns = ((E + 1) / clock)[src]
+            wake = arr + xgmi_lat
+            np.maximum(wake, minns, out=wake)
+            sc = np.rint(wake * clock).astype(np.int64)
+            ms = int(sc.max())
+            if ms > max_set:
+                max_set = ms
+            if refs[uid] > 0:
+                M = np.zeros((n, n), np.int64)
+                M[src, dst] = sc
+                sc_store[uid] = M
+            account(fam, n - 1, ar_n)
+            cls_msgs += np.bincount(legs["cls"], minlength=C)
+            cls_bytes += np.bincount(legs["cls"], minlength=C) * fam.nb
 
-    for ins in plan.instrs:
-        tag = ins[0]
-        if tag == "p":
-            _, gi, dur, td, fam, uid, _k = ins
-            if dur:
-                T[gi] += dur
-            if fam is not None:
-                if fam.kind == "fanout_all":
-                    emit_fanout(fam, uid)
-                else:
-                    emit_family(fam, uid)
-            tdapply(gi, td)
-        elif tag == "w":
-            _, gi, cols, td = ins
-            g = len(groups[gi].devs)
-            for col in cols:
-                V = np.empty(g, np.int64)
-                for uid, idx, rows in col:
-                    V[idx] = sc_store[uid][rows]
-                    refs[uid] -= 1
-                    if refs[uid] == 0:
-                        del sc_store[uid]
-                spin(gi, V)
-            tdapply(gi, td)
-        else:  # "aw"
-            _, gi, uid, td = ins
-            M = sc_store[uid]
-            for j in range(n - 1):
-                gidx = np.where(ar_n > j, j, j + 1)
-                spin(gi, M[gidx, ar_n])
-            refs[uid] -= 1
-            if refs[uid] == 0:
-                del sc_store[uid]
-            tdapply(gi, td)
-
-    solve_done = time.perf_counter()
+        for ins in plan.instrs:
+            tag = ins[0]
+            if tag == "p":
+                _, gi, dur, td, fam, uid, _k = ins
+                if dur:
+                    T[gi] += dur
+                if fam is not None:
+                    if fam.kind == "fanout_all":
+                        emit_fanout(fam, uid)
+                    else:
+                        emit_family(fam, uid)
+                tdapply(gi, td)
+            elif tag == "w":
+                _, gi, cols, td = ins
+                g = len(groups[gi].devs)
+                for col in cols:
+                    V = np.empty(g, np.int64)
+                    for uid, idx, rows in col:
+                        V[idx] = sc_store[uid][rows]
+                        refs[uid] -= 1
+                        if refs[uid] == 0:
+                            del sc_store[uid]
+                    spin(gi, V)
+                tdapply(gi, td)
+            else:  # "aw"
+                _, gi, uid, td = ins
+                M = sc_store[uid]
+                for j in range(n - 1):
+                    gidx = np.where(ar_n > j, j, j + 1)
+                    spin(gi, M[gidx, ar_n])
+                refs[uid] -= 1
+                if refs[uid] == 0:
+                    del sc_store[uid]
+                tdapply(gi, td)
 
     # ---- write-back -----------------------------------------------------
-    kend = np.zeros(n, np.int64)
-    for gi, grp in enumerate(groups):
-        kend[grp.devs] = T[gi].max(axis=1)
-    sim_cycles = max(int(kend.max()), max_set)
-    for r, node in enumerate(cluster.nodes):
-        t = node.memory.traffic
-        t.flag_reads += int(a_fr[r])
-        t.nonflag_reads += int(a_nfr[r])
-        t.read_bytes += int(a_rb[r])
-        t.local_writes += int(a_lw[r])
-        t.write_bytes += int(a_wb[r])
-        t.xgmi_writes_out += int(a_xo[r])
-        t.xgmi_bytes_out += int(a_xob[r])
-        t.xgmi_writes_in += int(a_xi[r])
-        t.xgmi_bytes_in += int(a_xib[r])
-        tgt = node.target
-        tgt.done_count = tgt.n_wgs
-        tgt.kernel_end_cycle = int(kend[r])
-        ws = node.wtt.stats
-        ws.registered += int(a_reg[r])
-        ws.enacted += int(a_reg[r])
-        if a_marks[r]:
-            cluster._data_marks[r] = (
-                cluster._data_marks.get(r, 0) + int(a_marks[r])
-            )
-    cluster._seq += seq_add
-    st = fab.stats
-    st["messages"] += g_msgs
-    st["bytes"] += g_bytes
-    st["queued_ns"] += g_q
-    for ci, cname in enumerate(ports.cls_names):
-        if cls_msgs[ci]:
-            st[f"{cname}_messages"] = (
-                st.get(f"{cname}_messages", 0) + int(cls_msgs[ci])
-            )
-            st[f"{cname}_bytes"] = (
-                st.get(f"{cname}_bytes", 0) + int(cls_bytes[ci])
-            )
-            st[f"{cname}_queued_ns"] = (
-                st.get(f"{cname}_queued_ns", 0.0) + float(cls_q[ci])
-            )
-    for p in np.flatnonzero(port_used):
-        p = int(p)
-        port = ports.tuples[p]
-        fab._busy_until_ns[port] = float(port_busy[p])
-        ps = fab.port_stats.get(port)
-        if ps is None:
-            ps = fab.port_stats[port] = [0, 0, 0.0]
-        ps[0] += int(port_cnt[p])
-        ps[1] += int(port_byt[p])
-        ps[2] += float(port_qd[p])
-    run_wall = time.perf_counter() - t0
-    breakdown.update(
-        solve_s=solve_done - t0,
-        writeback_s=run_wall - (solve_done - t0),
-    )
+    with span("lockstep.writeback") as writeback:
+        kend = np.zeros(n, np.int64)
+        for gi, grp in enumerate(groups):
+            kend[grp.devs] = T[gi].max(axis=1)
+        sim_cycles = max(int(kend.max()), max_set)
+        for r, node in enumerate(cluster.nodes):
+            t = node.memory.traffic
+            t.flag_reads += int(a_fr[r])
+            t.nonflag_reads += int(a_nfr[r])
+            t.read_bytes += int(a_rb[r])
+            t.local_writes += int(a_lw[r])
+            t.write_bytes += int(a_wb[r])
+            t.xgmi_writes_out += int(a_xo[r])
+            t.xgmi_bytes_out += int(a_xob[r])
+            t.xgmi_writes_in += int(a_xi[r])
+            t.xgmi_bytes_in += int(a_xib[r])
+            tgt = node.target
+            tgt.done_count = tgt.n_wgs
+            tgt.kernel_end_cycle = int(kend[r])
+            ws = node.wtt.stats
+            ws.registered += int(a_reg[r])
+            ws.enacted += int(a_reg[r])
+            if a_marks[r]:
+                cluster._data_marks[r] = (
+                    cluster._data_marks.get(r, 0) + int(a_marks[r])
+                )
+        cluster._seq += seq_add
+        st = fab.stats
+        st["messages"] += g_msgs
+        st["bytes"] += g_bytes
+        st["queued_ns"] += g_q
+        for ci, cname in enumerate(ports.cls_names):
+            if cls_msgs[ci]:
+                st[f"{cname}_messages"] = (
+                    st.get(f"{cname}_messages", 0) + int(cls_msgs[ci])
+                )
+                st[f"{cname}_bytes"] = (
+                    st.get(f"{cname}_bytes", 0) + int(cls_bytes[ci])
+                )
+                st[f"{cname}_queued_ns"] = (
+                    st.get(f"{cname}_queued_ns", 0.0) + float(cls_q[ci])
+                )
+        for p in np.flatnonzero(port_used):
+            p = int(p)
+            port = ports.tuples[p]
+            fab._busy_until_ns[port] = float(port_busy[p])
+            ps = fab.port_stats.get(port)
+            if ps is None:
+                ps = fab.port_stats[port] = [0, 0, 0.0]
+            ps[0] += int(port_cnt[p])
+            ps[1] += int(port_byt[p])
+            ps[2] += float(port_qd[p])
+    breakdown.update(solve_s=solve.dur, writeback_s=writeback.dur)
     return EngineResult(
         sim_cycles=sim_cycles,
-        wall_time_s=run_wall + breakdown.get("compile_s", 0.0),
+        wall_time_s=solve.dur + writeback.dur + breakdown.get("compile_s", 0.0),
         head_polls=0,
         breakdown=breakdown,
     )

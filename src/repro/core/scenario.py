@@ -47,6 +47,7 @@ from .config import EngineKind, SimConfig
 from .events import TraceBundle
 from .interconnect import V5E, FabricLike, HardwareSpec, resolve_fabric
 from .memory import AddressMap
+from .spans import Recorder, span
 
 __all__ = [
     "TrafficOp",
@@ -891,63 +892,75 @@ def simulate(
     routes.  Together they make 1024-4096 device collectives — flat and
     tiered — practical; ``Report.meta["lockstep_reason"]`` records either
     ``"engaged"`` or the exact reason the solvers declined.
+
+    Each call records its layers' spans and counters
+    (:mod:`repro.core.spans`) into ``Report.meta["spans"]`` and
+    ``Report.meta["counters"]``.
     """
     from .simulator import Eidola  # late import: simulator imports target
 
-    devices, dpn = _resolve_shape(devices, nodes, devices_per_node)
-    if dpn is not None:
-        params.setdefault("devices_per_node", dpn)
-    if devices is not None:
-        cfg = (cfg or SimConfig()).with_devices(devices)
-    if isinstance(scenario, Scenario):
-        # the instance's programs/traces were built from its cfg; running the
-        # engines under another cfg would silently mix two configurations
-        if cfg is not None and cfg != scenario.cfg:
-            raise ValueError(
-                "scenario instance was built with a different SimConfig than "
-                "the one passed to simulate(); rebuild the scenario or drop "
-                "the cfg/devices arguments"
-            )
-        cfg = scenario.cfg
-    cfg = (cfg or SimConfig()).validate()
-    sc = _resolve(scenario, cfg, params)
-    if sc.closed_loop:
-        from .cluster import Cluster  # late import: cluster imports target
+    with Recorder("eidola.simulate") as rec:
+        with span("entry.scenario"):
+            devices, dpn = _resolve_shape(devices, nodes, devices_per_node)
+            if dpn is not None:
+                params.setdefault("devices_per_node", dpn)
+            if devices is not None:
+                cfg = (cfg or SimConfig()).with_devices(devices)
+            if isinstance(scenario, Scenario):
+                # the instance's programs/traces were built from its cfg; running the
+                # engines under another cfg would silently mix two configurations
+                if cfg is not None and cfg != scenario.cfg:
+                    raise ValueError(
+                        "scenario instance was built with a different SimConfig than "
+                        "the one passed to simulate(); rebuild the scenario or drop "
+                        "the cfg/devices arguments"
+                    )
+                cfg = scenario.cfg
+            cfg = (cfg or SimConfig()).validate()
+            sc = _resolve(scenario, cfg, params)
+        if sc.closed_loop:
+            from .cluster import Cluster  # late import: cluster imports target
 
-        return Cluster(
-            cfg,
-            sc,
-            perturb=perturb,
-            collect_segments=collect_segments,
-            sanitize=sanitize,
-            timeline=timeline,
-            lockstep=lockstep,
-            plan_cache=_plan_cache,
-            plan_key=_plan_key,
-        ).run()
-    if sanitize:
-        raise ValueError(
-            "sanitize=True requires a closed-loop scenario (the sanitizer "
-            "shadows the cluster's fabric and directory accounting)"
-        )
-    if timeline is True:
-        raise ValueError(
-            "timeline=True requires a closed-loop scenario (the timeline "
-            "engine drives a Cluster of lockstep lanes)"
-        )
-    if lockstep is True:
-        raise ValueError(
-            "lockstep=True requires a closed-loop scenario (the bulk solver "
-            "advances a Cluster of rank-uniform symbolic programs)"
-        )
-    return Eidola(
-        cfg,
-        sc.traces(),
-        scenario=sc,
-        amap=sc.amap,
-        perturb=perturb,
-        collect_segments=collect_segments,
-    ).run()
+            report = Cluster(
+                cfg,
+                sc,
+                perturb=perturb,
+                collect_segments=collect_segments,
+                sanitize=sanitize,
+                timeline=timeline,
+                lockstep=lockstep,
+                plan_cache=_plan_cache,
+                plan_key=_plan_key,
+            ).run()
+        else:
+            if sanitize:
+                raise ValueError(
+                    "sanitize=True requires a closed-loop scenario (the sanitizer "
+                    "shadows the cluster's fabric and directory accounting)"
+                )
+            if timeline is True:
+                raise ValueError(
+                    "timeline=True requires a closed-loop scenario (the timeline "
+                    "engine drives a Cluster of lockstep lanes)"
+                )
+            if lockstep is True:
+                raise ValueError(
+                    "lockstep=True requires a closed-loop scenario (the bulk solver "
+                    "advances a Cluster of rank-uniform symbolic programs)"
+                )
+            with span("entry.traces"):
+                traces = sc.traces()
+            report = Eidola(
+                cfg,
+                traces,
+                scenario=sc,
+                amap=sc.amap,
+                perturb=perturb,
+                collect_segments=collect_segments,
+            ).run()
+    report.meta["spans"] = rec.spans
+    report.meta["counters"] = rec.counters
+    return report
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,6 @@ wall-clock simulation time).
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -18,6 +17,7 @@ from .events import Segment, TraceBundle, effective_writes
 from .memory import AddressMap, DirectoryMemory
 from .monitor import MonitorLog
 from .scenario import Scenario
+from .spans import span
 from .target import TargetDevice
 from .wtt import WriteTrackingTable
 
@@ -146,31 +146,33 @@ class Eidola:
                     "use EngineKind.CYCLE or EngineKind.EVENT"
                 )
             return report
-        memory, monitor, device, wtt = self._build()
+        with span("engine.setup"):
+            memory, monitor, device, wtt = self._build()
         engine = (
             CyclePollEngine() if cfg.engine == EngineKind.CYCLE else EventQueueEngine()
         )
         res = engine.run(device, wtt)
-        return Report(
-            engine=engine.name,
-            sync=cfg.sync.value,
-            traffic=memory.traffic.as_dict(),
-            flag_reads=memory.traffic.flag_reads,
-            nonflag_reads=memory.traffic.nonflag_reads,
-            kernel_span_ns=cfg.cycles_to_ns(device.kernel_end_cycle),
-            sim_cycles=res.sim_cycles,
-            wall_time_s=res.wall_time_s,
-            wtt_registered=wtt.stats.registered,
-            wtt_enacted=wtt.stats.enacted,
-            wtt_head_polls=res.head_polls,
-            scenario=self.scenario.name,
-            monitor_stats=dict(monitor.stats) if monitor else {},
-            segments=device.collect_segments() if self.collect_segments else [],
-            meta=dict(self.traces.meta),
-            n_devices=1,
-            per_device={0: memory.traffic.as_dict()},
-            closed_loop=False,
-        )
+        with span("entry.report"):
+            return Report(
+                engine=engine.name,
+                sync=cfg.sync.value,
+                traffic=memory.traffic.as_dict(),
+                flag_reads=memory.traffic.flag_reads,
+                nonflag_reads=memory.traffic.nonflag_reads,
+                kernel_span_ns=cfg.cycles_to_ns(device.kernel_end_cycle),
+                sim_cycles=res.sim_cycles,
+                wall_time_s=res.wall_time_s,
+                wtt_registered=wtt.stats.registered,
+                wtt_enacted=wtt.stats.enacted,
+                wtt_head_polls=res.head_polls,
+                scenario=self.scenario.name,
+                monitor_stats=dict(monitor.stats) if monitor else {},
+                segments=device.collect_segments() if self.collect_segments else [],
+                meta=dict(self.traces.meta),
+                n_devices=1,
+                per_device={0: memory.traffic.as_dict()},
+                closed_loop=False,
+            )
 
 
 def run_gemv_allreduce(
