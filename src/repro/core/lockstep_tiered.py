@@ -60,7 +60,7 @@ from .scenario import (
     LoopSpec,
     as_symbolic,
 )
-from .spans import span
+from .spans import count, span
 
 __all__ = ["compile_tiered", "run_tiered"]
 
@@ -1218,10 +1218,34 @@ def _chain(b0, rdy, ser):
     return starts, b
 
 
+def _as_slice(idx):
+    """``idx`` as a slice selecting the same elements when it is an
+    ascending arithmetic progression (a slice indexes by view, with no
+    gather or scatter), else ``idx`` itself."""
+    if len(idx) < 2:
+        return idx
+    step = int(idx[1] - idx[0])
+    if step <= 0 or not np.array_equal(
+        np.diff(idx), np.full(len(idx) - 1, step)
+    ):
+        return idx
+    return slice(int(idx[0]), int(idx[-1]) + 1, step)
+
+
 def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
     """Solve the compiled tiered plan; mutates cluster state only in the
     final write-back (a mid-solve failure falls back to the timeline engine
-    cleanly)."""
+    cleanly).
+
+    The per-step loop carries only the float recurrence (port busy chains,
+    arrivals, wakes) and the spin's cursor update.  Integer accounting is
+    order-free, so it is tallied per family and per group (emissions,
+    ``tdelta`` applications, spin polls) and applied once at the write-back.
+    A leg slot in which no message waits for its port (every start equals
+    its ready time) would add ``+0.0`` to each queue sum, which leaves a
+    non-negative float unchanged, so it skips those adds; the float adds
+    that remain keep their order.
+    """
     with span("lockstep.solve") as solve:
         cfg = cluster.cfg
         n = cfg.n_devices
@@ -1249,83 +1273,75 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
         cls_msgs = np.zeros(C, np.int64)
         cls_bytes = np.zeros(C, np.int64)
         cls_q = np.zeros(C)
-        g_msgs = 0
-        g_bytes = 0
         g_q = 0.0
-        seq_add = 0
         max_set = 0
-
-        a_fr = np.zeros(n, np.int64)
-        a_rb = np.zeros(n, np.int64)
-        a_nfr = np.zeros(n, np.int64)
-        a_lw = np.zeros(n, np.int64)
-        a_wb = np.zeros(n, np.int64)
-        a_xo = np.zeros(n, np.int64)
-        a_xob = np.zeros(n, np.int64)
-        a_xi = np.zeros(n, np.int64)
-        a_xib = np.zeros(n, np.int64)
-        a_reg = np.zeros(n, np.int64)
-        a_marks = np.zeros(n, np.int64)
 
         T = [np.tile(g.dispatch, (len(g.devs), 1)) for g in groups]
         sc_store: Dict[int, np.ndarray] = {}
-        refs = plan.refs.copy()
+        refs = plan.refs.tolist()
+
+        # order-free integer tallies, applied at the write-back
+        emitted: Dict[_Fam, int] = {}  # family -> instances emitted
+        td_cnt: List[Dict[tuple, int]] = [{} for _ in groups]  # tdelta -> n
+        polls = [np.zeros_like(t) for t in T]  # summed spin poll counts
+        spins = [0] * len(groups)
+        # per-call constants of each elementwise family's leg slots:
+        # (messages, ports, serialization ns, hop latency ns, link classes)
+        elem_slots: Dict[_Fam, list] = {}
+        n_slots = 0
+        n_quiet = 0
 
         def spin(gi, V):
             """The interpreter's unified spin closed form over one group's
             cursor matrix (one wait address per rank)."""
-            grp = groups[gi]
             nt = V[:, None] - T[gi]
             nt += poll - 1
             nt //= poll
             np.maximum(nt, 0, out=nt)
-            m = nt @ grp.counts
-            m += grp.total
-            a_fr[grp.devs] += m
-            a_rb[grp.devs] += 8 * m
+            polls[gi] += nt
+            spins[gi] += 1
             nt *= poll
             nt += check
             T[gi] += nt
 
-        def tdapply(gi, d):
-            if d is None:
-                return
-            grp = groups[gi]
-            tot = grp.total
-            devs = grp.devs
-            if d[0]:
-                a_nfr[devs] += d[0] * tot
-            if d[1]:
-                a_rb[devs] += d[1] * tot
-            if d[2]:
-                a_lw[devs] += d[2] * tot
-            if d[3]:
-                a_wb[devs] += d[3] * tot
-            if d[4]:
-                a_xo[devs] += d[4] * tot
-            if d[5]:
-                a_xob[devs] += d[5] * tot
+        def tally(gi, d):
+            if d is not None:
+                c = td_cnt[gi]
+                c[d] = c.get(d, 0) + 1
 
-        def price_elem(fam, issue):
-            """Leg-by-leg elementwise pricing; valid because no two messages of
-            the instance share a port (checked at compile)."""
-            nonlocal g_q
-            nb = fam.nb
-            arr = issue.copy()
-            for mi, prt, hops, cls in fam.leg_slots:
+        def slots_of(fam):
+            s = elem_slots.get(fam)
+            if s is None:
+                nb = fam.nb
+                s = elem_slots[fam] = [
+                    (_as_slice(mi), _as_slice(prt), nb / port_bw[prt],
+                     hops * port_lat[prt], cls)
+                    for mi, prt, hops, cls in fam.leg_slots
+                ]
+            return s
+
+        def price_elem(fam, arr):
+            """Leg-by-leg elementwise pricing of ``arr`` (issue times, priced
+            in place into arrivals); valid because no two messages of the
+            instance share a port (checked at compile)."""
+            nonlocal g_q, n_slots, n_quiet
+            slots = slots_of(fam)
+            n_slots += len(slots)
+            for mi, prt, ser, lat, cls in slots:
+                # ``rdy`` is a view of ``arr`` where ``mi`` is a slice, so
+                # it is read before ``arr[mi]`` is written
                 rdy = arr[mi]
                 st = np.maximum(rdy, port_busy[prt])
-                ser = nb / port_bw[prt]
                 fin = st + ser
                 port_busy[prt] = fin
-                port_used[prt] = True
-                q = st - rdy
-                port_cnt[prt] += 1
-                port_byt[prt] += nb
-                port_qd[prt] += q
-                g_q += float(q.sum())
-                np.add.at(cls_q, cls, q)
-                arr[mi] = fin + hops * port_lat[prt]
+                if np.count_nonzero(st != rdy):
+                    q = st - rdy
+                    port_qd[prt] += q
+                    g_q += float(q.sum())
+                    np.add.at(cls_q, cls, q)
+                else:
+                    n_quiet += 1
+                arr[mi] = fin + lat
             return arr
 
         def price_ordered(fam, issue, E_msg, legs):
@@ -1415,63 +1431,38 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
                     )
             return arr_out
 
-        def account(fam, nmsg_per_rank, devs):
-            nonlocal seq_add, g_msgs, g_bytes
-            nonlocal a_xi, a_xib, a_reg, a_marks
-            dw = fam.dw if include_dw and fam.dw > 0 else 0
-            regs = 1 + dw
-            a_xo[devs] += nmsg_per_rank
-            a_xob[devs] += nmsg_per_rank * fam.size
-            if fam.kind == "fanout_all":
-                a_xi += nmsg_per_rank * regs
-                a_xib += nmsg_per_rank * (fam.size + 8 * dw)
-                a_reg += nmsg_per_rank * regs
-                if dw:
-                    a_marks += nmsg_per_rank * dw
-            elif fam.dst_unique:
-                a_xi[fam.dst] += regs
-                a_xib[fam.dst] += fam.size + 8 * dw
-                a_reg[fam.dst] += regs
-                if dw:
-                    a_marks[fam.dst] += dw
-            else:
-                np.add.at(a_xi, fam.dst, regs)
-                np.add.at(a_xib, fam.dst, fam.size + 8 * dw)
-                np.add.at(a_reg, fam.dst, regs)
-                if dw:
-                    np.add.at(a_marks, fam.dst, dw)
-            seq_add += fam.m * regs
-            g_msgs += fam.m
-            g_bytes += fam.m * fam.nb
+        def wake_cycles(arr, minns):
+            """Set cycles of priced arrivals ``arr`` (consumed in place)."""
+            nonlocal max_set
+            arr += xgmi_lat
+            np.maximum(arr, minns, out=arr)
+            arr *= clock
+            sc = np.rint(arr, out=arr).astype(np.int64)
+            ms = int(sc.max())
+            if ms > max_set:
+                max_set = ms
+            return sc
 
         def emit_family(fam, uid):
-            nonlocal max_set, cls_msgs, cls_bytes
-            gi = fam.gi
-            grp = groups[gi]
-            E = T[gi].max(axis=1)
-            issue_r = E / clock
-            minns_r = (E + 1) / clock
-            issue = issue_r[fam.src_row]
+            Tg = T[fam.gi]
+            # a single cohort's column is its own maximum
+            E = Tg[:, 0] if Tg.shape[1] == 1 else Tg.max(axis=1)
+            issue = E / clock
+            minns = (E + 1) / clock
+            if fam.kind != "single":  # "single": src_row is the identity
+                issue = issue[fam.src_row]
+                minns = minns[fam.src_row]
             if fam.pricing == "elem":
                 arr = price_elem(fam, issue)
             else:
                 arr = price_ordered(fam, issue, E[fam.src_row], fam.legs)
-            wake = arr + xgmi_lat
-            np.maximum(wake, minns_r[fam.src_row], out=wake)
-            sc = np.rint(wake * clock).astype(np.int64)
-            ms = int(sc.max())
-            if ms > max_set:
-                max_set = ms
+            sc = wake_cycles(arr, minns)
             if refs[uid] > 0:
                 sc_store[uid] = sc
-            account(fam, fam.cnt, grp.devs)
-            cls_msgs += fam.cls_legs
-            cls_bytes += fam.cls_legs * fam.nb
+            emitted[fam] = emitted.get(fam, 0) + 1
 
         def emit_fanout(fam, uid):
-            nonlocal max_set, cls_msgs, cls_bytes
-            gi = fam.gi
-            E = T[gi].max(axis=1)
+            E = T[fam.gi].max(axis=1)
             src = np.repeat(np.arange(n, dtype=np.int32), n - 1)
             dstm = np.tile(np.arange(n - 1, dtype=np.int32), (n, 1))
             dstm += dstm >= ar_n[:, None]
@@ -1480,20 +1471,15 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
             _spot_check(ports, fab, src, dst, legs)
             issue = (E / clock)[src]
             arr = price_ordered(fam, issue, E[src], legs)
-            minns = ((E + 1) / clock)[src]
-            wake = arr + xgmi_lat
-            np.maximum(wake, minns, out=wake)
-            sc = np.rint(wake * clock).astype(np.int64)
-            ms = int(sc.max())
-            if ms > max_set:
-                max_set = ms
+            sc = wake_cycles(arr, ((E + 1) / clock)[src])
             if refs[uid] > 0:
                 M = np.zeros((n, n), np.int64)
                 M[src, dst] = sc
                 sc_store[uid] = M
-            account(fam, n - 1, ar_n)
-            cls_msgs += np.bincount(legs["cls"], minlength=C)
-            cls_bytes += np.bincount(legs["cls"], minlength=C) * fam.nb
+            emitted[fam] = emitted.get(fam, 0) + 1
+            legs_cls = np.bincount(legs["cls"], minlength=C)
+            cls_msgs[:] += legs_cls
+            cls_bytes[:] += legs_cls * fam.nb
 
         for ins in plan.instrs:
             tag = ins[0]
@@ -1506,7 +1492,7 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
                         emit_fanout(fam, uid)
                     else:
                         emit_family(fam, uid)
-                tdapply(gi, td)
+                tally(gi, td)
             elif tag == "w":
                 _, gi, cols, td = ins
                 g = len(groups[gi].devs)
@@ -1518,7 +1504,7 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
                         if refs[uid] == 0:
                             del sc_store[uid]
                     spin(gi, V)
-                tdapply(gi, td)
+                tally(gi, td)
             else:  # "aw"
                 _, gi, uid, td = ins
                 M = sc_store[uid]
@@ -1528,10 +1514,79 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
                 refs[uid] -= 1
                 if refs[uid] == 0:
                     del sc_store[uid]
-                tdapply(gi, td)
+                tally(gi, td)
+        count("lockstep.steps", len(plan.instrs))
+        count("lockstep.slots", n_slots)
+        count("lockstep.quiet_slots", n_quiet)
 
     # ---- write-back -----------------------------------------------------
     with span("lockstep.writeback") as writeback:
+        a_fr = np.zeros(n, np.int64)
+        a_rb = np.zeros(n, np.int64)
+        a_nfr = np.zeros(n, np.int64)
+        a_lw = np.zeros(n, np.int64)
+        a_wb = np.zeros(n, np.int64)
+        a_xo = np.zeros(n, np.int64)
+        a_xob = np.zeros(n, np.int64)
+        a_xi = np.zeros(n, np.int64)
+        a_xib = np.zeros(n, np.int64)
+        a_reg = np.zeros(n, np.int64)
+        a_marks = np.zeros(n, np.int64)
+        seq_add = 0
+        g_msgs = 0
+        g_bytes = 0
+        for gi, grp in enumerate(groups):
+            devs = grp.devs
+            tot = grp.total
+            m = polls[gi] @ grp.counts + spins[gi] * tot
+            a_fr[devs] += m
+            a_rb[devs] += 8 * m
+            d = [0] * 6
+            for td, c in td_cnt[gi].items():
+                for j in range(6):
+                    d[j] += c * td[j]
+            a_nfr[devs] += d[0] * tot
+            a_rb[devs] += d[1] * tot
+            a_lw[devs] += d[2] * tot
+            a_wb[devs] += d[3] * tot
+            a_xo[devs] += d[4] * tot
+            a_xob[devs] += d[5] * tot
+        for fam, c in emitted.items():
+            dw = fam.dw if include_dw and fam.dw > 0 else 0
+            regs = 1 + dw
+            per_rank = c * fam.cnt
+            devs = groups[fam.gi].devs
+            a_xo[devs] += per_rank
+            a_xob[devs] += per_rank * fam.size
+            if fam.kind == "fanout_all":
+                a_xi += per_rank * regs
+                a_xib += per_rank * (fam.size + 8 * dw)
+                a_reg += per_rank * regs
+                if dw:
+                    a_marks += per_rank * dw
+            elif fam.dst_unique:
+                a_xi[fam.dst] += c * regs
+                a_xib[fam.dst] += c * (fam.size + 8 * dw)
+                a_reg[fam.dst] += c * regs
+                if dw:
+                    a_marks[fam.dst] += c * dw
+            else:
+                np.add.at(a_xi, fam.dst, c * regs)
+                np.add.at(a_xib, fam.dst, c * (fam.size + 8 * dw))
+                np.add.at(a_reg, fam.dst, c * regs)
+                if dw:
+                    np.add.at(a_marks, fam.dst, c * dw)
+            seq_add += c * fam.m * regs
+            g_msgs += c * fam.m
+            g_bytes += c * fam.m * fam.nb
+            if fam.cls_legs is not None:
+                cls_msgs += c * fam.cls_legs
+                cls_bytes += c * fam.cls_legs * fam.nb
+            if fam.pricing == "elem":
+                prt = fam.legs["port"]
+                port_used[prt] = True
+                port_cnt[prt] += c
+                port_byt[prt] += c * fam.nb
         kend = np.zeros(n, np.int64)
         for gi, grp in enumerate(groups):
             kend[grp.devs] = T[gi].max(axis=1)

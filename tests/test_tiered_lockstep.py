@@ -2,12 +2,19 @@
 
 Seeded-random cross-engine identity and group-IR round-trips that must run
 unconditionally (the hypothesis-driven shape sweep lives in
-``test_property.py`` and is skipped when hypothesis is absent).
+``test_property.py`` and is skipped when hypothesis is absent), the
+solver's float results pinned exactly, and its step and slot counters.
 """
 
+import math
 import random
 
+import numpy as np
+import pytest
+
 from repro.core import EngineKind, SimConfig
+from repro.core.cluster import Cluster
+from repro.core.lockstep_tiered import _as_slice, compile_tiered
 from repro.core.scenario import get_scenario, simulate
 
 _KEYS = (
@@ -166,3 +173,141 @@ def test_group_classification_roundtrips_expand():
                 expanded = [p.name for p in sp.expand()]
                 assert sched == expanded, (name, n, dpn, dev)
         assert seen == set(range(n)), (name, n, dpn)
+
+
+# The solver's float results, recorded before its per-step loop was
+# restructured (deferred integer tallies, hoisted leg constants, skipped
+# all-zero queue adds): (scenario, fabric, devices, devices_per_node) ->
+# sim_cycles, every ``*queued_ns`` of the fabric's stats, and the exact sum
+# (``math.fsum``) of the per-port queue times.  The last case is one in which
+# no message ever waits for its port.
+_PINNED = {
+    ("ring_allreduce", "two_tier", 12, 4): (
+        298741,
+        {"dci_queued_ns": 48614.39999999991, "ici_queued_ns": 0.0,
+         "queued_ns": 48614.3999999999},
+        48614.3999999999,
+    ),
+    ("ring_allreduce", "fat_tree", 12, 4): (
+        673333,
+        {"dci_queued_ns": 48506.88, "ici_queued_ns": 0.0,
+         "queued_ns": 193273.54666666663,
+         "spine_queued_ns": 144766.66666666663},
+        193273.54666666663,
+    ),
+    ("ring_allreduce", "rail_optimized", 12, 4): (
+        298741,
+        {"ici_queued_ns": 0.0, "queued_ns": 48614.3999999999,
+         "rail_queued_ns": 48614.39999999991},
+        48614.3999999999,
+    ),
+    ("all_to_all", "two_tier", 12, 4): (
+        405868,
+        {"dci_queued_ns": 8628518.400000002, "ici_queued_ns": 6519499.2,
+         "queued_ns": 15148017.600000001},
+        15148017.600000001,
+    ),
+    ("all_to_all", "fat_tree", 12, 4): (
+        1555628,
+        {"dci_queued_ns": 22298081.59999999, "ici_queued_ns": 5611851.599999999,
+         "queued_ns": 42674502.79999998, "spine_queued_ns": 14764569.6},
+        42674502.79999999,
+    ),
+    ("all_to_all", "rail_optimized", 12, 4): (
+        222380,
+        {"ici_queued_ns": 755006.3999999999, "queued_ns": 5173191.999999999,
+         "rail_queued_ns": 4418185.6},
+        5173192.0,
+    ),
+    ("hierarchical_allreduce", "two_tier", 12, 4): (
+        355600,
+        {"dci_queued_ns": 0.0, "ici_queued_ns": 62915.03999999998,
+         "queued_ns": 62915.03999999998},
+        62915.03999999998,
+    ),
+    ("hierarchical_allreduce", "fat_tree", 12, 4): (
+        925200,
+        {"dci_queued_ns": 0.0, "ici_queued_ns": 62915.040000000095,
+         "queued_ns": 62915.040000000095, "spine_queued_ns": 0.0},
+        62915.040000000095,
+    ),
+    ("hierarchical_allreduce", "rail_optimized", 12, 4): (
+        355600,
+        {"ici_queued_ns": 62915.03999999998, "queued_ns": 62915.03999999998,
+         "rail_queued_ns": 0.0},
+        62915.03999999998,
+    ),
+    ("ring_allreduce", "rail_optimized", 64, 8): (
+        813168,
+        {"ici_queued_ns": 0.0, "queued_ns": 0.0, "rail_queued_ns": 0.0},
+        0.0,
+    ),
+}
+
+
+def _pod_scenario(name, fabric, n, dpn):
+    cfg = SimConfig(engine=EngineKind.EVENT, workgroups=4).with_devices(n)
+    return cfg, get_scenario(name)(
+        cfg, closed_loop=True, devices_per_node=dpn, fabric=fabric,
+    )
+
+
+@pytest.mark.parametrize("case", list(_PINNED),
+                         ids=lambda c: "-".join(map(str, c)))
+def test_solver_floats_pinned(case):
+    # the float recurrence keeps every add in its order, so the queue sums
+    # and the cycle count stay bit-for-bit what they were
+    cycles, queued, port_q = _PINNED[case]
+    cfg, sc = _pod_scenario(*case)
+    cluster = Cluster(cfg, sc, collect_segments=False, lockstep=True)
+    r = cluster.run()
+    assert r.meta["lockstep_reason"] == "engaged"
+    assert r.sim_cycles == cycles
+    assert {k: v for k, v in r.meta["fabric"].items()
+            if k.endswith("queued_ns")} == queued
+    assert math.fsum(
+        ps[2] for ps in cluster.fabric.port_stats.values()
+    ) == port_q
+
+
+@pytest.mark.parametrize("fabric, n, dpn, quiet", [
+    ("rail_optimized", 64, 8, True),  # no message waits for its port
+    ("two_tier", 12, 4, False),       # the DCI uplinks queue
+])
+def test_solver_counters(fabric, n, dpn, quiet):
+    # lockstep.steps counts the plan's instructions, lockstep.slots every
+    # elementwise leg-slot pricing, lockstep.quiet_slots those with no queue
+    cfg, sc = _pod_scenario("ring_allreduce", fabric, n, dpn)
+    plan = compile_tiered(Cluster(cfg, sc, collect_segments=False))
+    slots = sum(
+        len(ins[4].leg_slots) for ins in plan.instrs
+        if ins[0] == "p" and ins[4] is not None and ins[4].pricing == "elem"
+    )
+    assert slots > 0
+    cfg, sc = _pod_scenario("ring_allreduce", fabric, n, dpn)
+    r = simulate(sc, lockstep=True, collect_segments=False)
+    c = r.meta["counters"]
+    assert c["lockstep.steps"] == len(plan.instrs)
+    assert c["lockstep.slots"] == slots
+    if quiet:
+        assert c["lockstep.quiet_slots"] == slots
+    else:
+        assert 0 <= c["lockstep.quiet_slots"] < slots
+
+
+@pytest.mark.parametrize("idx, as_slice", [
+    ([0, 2, 4, 6], True),
+    ([7, 15, 23], True),
+    ([3, 4], True),
+    ([2, 4, 6, 10], False),   # the step changes
+    ([1015, 0, 1, 2], False),  # descends, then ascends
+    ([5, 5, 5], False),        # repeats: a scatter must not collapse them
+    ([4], False),
+    ([], False),
+])
+def test_index_progressions_become_slices(idx, as_slice):
+    a = np.array(idx, np.int64)
+    got = _as_slice(a)
+    assert isinstance(got, slice) is as_slice
+    src = np.arange(2000) * 3
+    assert np.array_equal(src[got], src[a])
