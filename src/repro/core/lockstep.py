@@ -167,7 +167,8 @@ class _SingleEmit:
 
 class _FanoutEmit:
     """All-peers fan-out: rank r sends one message to every other rank in
-    ascending order, all carrying rank r's flag address ``addr_vec[r]``."""
+    ascending order, all carrying rank r's flag address ``addr_vec[r]``.
+    ``payload`` is one int, or int64[n*(n-1)] in that message order."""
 
     __slots__ = ("addr_vec", "payload", "size", "dw")
 
@@ -302,7 +303,11 @@ def _classify_wait(specs, k0, count, n):
 
 
 def _classify_emit(amap, specs, k0, count, n):
-    """None, :class:`_SingleEmit`, or :class:`_FanoutEmit`."""
+    """None, :class:`_SingleEmit`, or :class:`_FanoutEmit`.
+
+    A fan-out whose messages carry different payloads gets one payload per
+    message, source-major and destination-ascending (only the tiered solver
+    prices those)."""
     if not specs[0].emits:
         for sp in specs:
             if sp.emits:
@@ -390,6 +395,7 @@ def _classify_emit(amap, specs, k0, count, n):
         )
     # -- all-peers fan-out: EmitRuns below/above self, ascending ----------
     payloads, sizes, dws, slot0s = set(), set(), set(), set()
+    runs = []  # each run's (payload, count), source-major
     for r, sp in enumerate(specs):
         want = [(0, r), (r + 1, n - 1 - r)]
         want = [w for w in want if w[1] > 0]
@@ -405,18 +411,27 @@ def _classify_emit(amap, specs, k0, count, n):
             )
             if not ok:
                 raise UnsupportedProgram("emits do not form an all-peers fan-out")
-            payloads.add(e.payload_bytes)
+            pay = e.payload_bytes
+            payloads.update(pay if isinstance(pay, tuple) else (pay,))
+            runs.append((pay, e.count))
             sizes.add(e.size)
             dws.add(e.data_writes)
             slot0s.add(e.slot0)
-    if len(payloads) != 1 or len(sizes) != 1 or len(dws) != 1 \
-            or len(slot0s) != 1:
+    if len(sizes) != 1 or len(dws) != 1 or len(slot0s) != 1:
         raise UnsupportedProgram("fan-out parameters vary across ranks")
+    if len(payloads) == 1:
+        payload = payloads.pop()
+    else:  # one payload per message, dst ascending within each run
+        payload = np.concatenate([
+            np.array(p, np.int64) if isinstance(p, tuple)
+            else np.full(c, p, np.int64)
+            for p, c in runs
+        ])
     slot0 = slot0s.pop()
     addr_vec = np.array(
         [amap.flag_addr(r, slot0) for r in range(len(specs))], np.int64
     )
-    return _FanoutEmit(addr_vec, payloads.pop(), sizes.pop(), dws.pop())
+    return _FanoutEmit(addr_vec, payload, sizes.pop(), dws.pop())
 
 
 def _phase_plan(amap, n, tdelta_for, specs, k0, count):
@@ -440,6 +455,8 @@ def _phase_plan(amap, n, tdelta_for, specs, k0, count):
                 raise UnsupportedProgram("wait phase with emissions")
     else:
         emit = _classify_emit(amap, specs, k0, count, n)
+        if isinstance(getattr(emit, "payload", None), np.ndarray):
+            raise UnsupportedProgram("fan-out payloads vary across messages")
     return _PhasePlan(name, is_wait, dur, tdelta, wait, emit)
 
 

@@ -567,6 +567,10 @@ def _classify_emit_group(amap, specs, devs, k0, count, n, gname, phname):
                 raise _unsupported(
                     f"non-contiguous EmitRun fan-out in {blame}"
                 )
+            if isinstance(e.payload_bytes, tuple):
+                raise _unsupported(
+                    f"per-destination payloads of a run fan-out in {blame}"
+                )
             dst0[i] = e.dst0
             cnts.add(e.count)
             slot0s.add(e.slot0)
@@ -663,15 +667,20 @@ def _gphase(amap, n, tdf, specs, devs, gname, k0, count) -> _GPhase:
                 f"phase structure of group {gname!r} varies across ranks "
                 f"(rank {devs[i]} differs from rank {devs[0]})"
             )
-    dur = 0 if is_wait else _uniform(
-        (sp.duration_cycles for sp in specs),
-        f"duration of phase {name!r} in group {gname!r}", ids=devs,
-    )
-    _uniform(
-        (sp.traffic for sp in specs),
-        f"traffic of phase {name!r} in group {gname!r}", ids=devs,
-    )
+    # a duration or traffic that differs across the group's ranks (a
+    # skewed all-to-all's expert phase) becomes a per-row vector: int64[g, 1]
+    # cycles, int64[g, 6] traffic deltas
+    dur = 0
+    if not is_wait:
+        durs = [sp.duration_cycles for sp in specs]
+        dur = durs[0]
+        if any(d != dur for d in durs):
+            dur = np.array(durs, np.int64)[:, None]
     tdelta = tdf(s0) if tdf is not None else None
+    if tdf is not None and any(sp.traffic != s0.traffic for sp in specs):
+        tdelta = np.array(
+            [tdf(sp) or (0,) * 6 for sp in specs], np.int64
+        )
     wait = emit = None
     if is_wait:
         for i, sp in enumerate(specs):
@@ -699,13 +708,16 @@ class _Fam:
     """One aligned emission position's route family, shared by its k
     instances.  Messages are enumerated source-major (group row order, dst
     ascending within a rank's run) — the event engine's per-firing op order.
+    ``nb`` is the bytes a message puts on each link: one int, or (an
+    all-peers fan-out with per-destination payloads) int64[m] in message
+    order; ``nb_sum`` is their total over the ``m`` messages.
     """
 
     __slots__ = (
         "gi", "fid", "kind", "pricing", "payload", "size", "dw", "nb",
-        "m", "cnt", "src_row", "src_dev", "dst", "addr_rel", "addr_step",
-        "legs", "leg_slots", "keys_sorted", "keys_order", "dst_unique",
-        "addr_vec", "cls_legs",
+        "nb_sum", "m", "cnt", "src_row", "src_dev", "dst", "addr_rel",
+        "addr_step", "legs", "leg_slots", "keys_sorted", "keys_order",
+        "dst_unique", "addr_vec", "cls_legs",
     )
 
 
@@ -753,6 +765,10 @@ def _build_fam(ports, fab, grp, gi, fid, e: _GEmit, n) -> _Fam:
         fam.pricing = "ordered"
         fam.m = n * (n - 1)
         fam.cnt = n - 1
+        fam.nb_sum = (
+            int(fam.nb.sum()) if isinstance(fam.nb, np.ndarray)
+            else fam.m * fam.nb
+        )
         fam.addr_vec = e.addr_base
         fam.legs = None  # built lazily at the (single) run instance
         fam.src_row = fam.src_dev = fam.dst = fam.addr_rel = None
@@ -774,6 +790,7 @@ def _build_fam(ports, fab, grp, gi, fid, e: _GEmit, n) -> _Fam:
         ).ravel()
         fam.addr_rel = np.repeat(e.addr_base, e.cnt)
     fam.m = len(fam.dst)
+    fam.nb_sum = fam.m * fam.nb
     fam.legs = _legs_csr(ports, fam.src_dev, fam.dst)
     _spot_check(ports, fab, fam.src_dev, fam.dst, fam.legs)
     # matching keys: (flag addr at k=0, dst) must identify each message
@@ -965,6 +982,28 @@ def _match_col(open_recs, want_addr, want_dst, n, cache):
     return None, int(np.flatnonzero(remaining)[0])
 
 
+def _fanouts_fenced(fams, instrs) -> bool:
+    """True when every emission family is an all-peers fan-out and each
+    instance's all-peers barrier comes before the next one is emitted.
+
+    The fan-out group is every rank, so the barrier holds each rank until
+    every peer has emitted the earlier instance: each of its messages is
+    then priced, in the event engine's order, before any message of the
+    next, and pricing instance by instance in plan order keeps that order
+    on every shared port."""
+    if any(f.kind != "fanout_all" for f in fams.values()):
+        return False
+    open_uid = None
+    for ins in instrs:
+        if ins[0] == "p" and ins[4] is not None:
+            if open_uid is not None:
+                return False
+            open_uid = ins[5]
+        elif ins[0] == "aw" and ins[2] == open_uid:
+            open_uid = None
+    return True
+
+
 def compile_tiered(cluster) -> _TieredPlan:
     """Group-align, classify, and schedule the pod's symbolic programs over
     a multi-tier fabric.  Raises :class:`UnsupportedProgram` with the
@@ -1141,7 +1180,8 @@ def compile_tiered(cluster) -> _TieredPlan:
                 "no group can advance (cyclic cross-group dependency)"
             )  # pragma: no cover
 
-    if any(f.kind == "fanout_all" for f in fams.values()) and len(fams) > 1:
+    if any(f.kind == "fanout_all" for f in fams.values()) and len(fams) > 1 \
+            and not _fanouts_fenced(fams, instrs):
         raise _unsupported(
             "all-peers fan-out cannot share link ports with other "
             "emission stages"
@@ -1173,14 +1213,19 @@ def _chain(b0, rdy, ser):
       exactly-carried busy value — crossing a chunk boundary reproduces
       the sequential float adds bit-for-bit, so chunking changes cost
       (quadratic -> amortized linear), never values.  The chunk doubles
-      while segments run long and snaps back small on a restart."""
+      while segments run long and snaps back small on a restart.
+
+    ``ser`` is one serialization time for every touch, or one per touch
+    (messages of different sizes); the adds are the same either way."""
     mlen = rdy.size
+    vec = isinstance(ser, np.ndarray)
     starts = np.empty(mlen)
     # iso[t]: element t+1 restarts given element t restarted
-    # (rdy[t+1] > busy_t = rdy[t] + ser, a single exact add)
+    # (rdy[t+1] > busy_t = rdy[t] + ser[t], a single exact add)
     iso = np.empty(mlen, bool)
     if mlen > 1:
-        np.greater(rdy[1:], rdy[:-1] + ser, out=iso[: mlen - 1])
+        np.greater(rdy[1:], rdy[:-1] + (ser[:-1] if vec else ser),
+                   out=iso[: mlen - 1])
     iso[mlen - 1] = False
     # nf[t]: first index >= t with iso False (run terminator)
     idx = np.arange(mlen, dtype=np.int64)
@@ -1195,14 +1240,14 @@ def _chain(b0, rdy, ser):
             # maximal restart run: every element's start is its own ready
             t = int(nf[i]) - i + 1
             starts[i: i + t] = rdy[i: i + t]
-            b = float(rdy[i + t - 1]) + ser
+            b = float(rdy[i + t - 1]) + (ser[i + t - 1] if vec else ser)
             i += t
             continue
         rem = mlen - i
         c = chunk if chunk < rem else rem
         ch = np.empty(c + 1)
         ch[0] = b
-        ch[1:] = ser
+        ch[1:] = ser[i: i + c] if vec else ser
         bs = np.cumsum(ch)
         viol = np.flatnonzero(rdy[i + 1: i + c] > bs[1:c])
         if viol.size:
@@ -1283,6 +1328,8 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
         # order-free integer tallies, applied at the write-back
         emitted: Dict[_Fam, int] = {}  # family -> instances emitted
         td_cnt: List[Dict[tuple, int]] = [{} for _ in groups]  # tdelta -> n
+        # per-row traffic deltas of phases whose traffic differs by rank
+        td_rows = [np.zeros((len(g.devs), 6), np.int64) for g in groups]
         polls = [np.zeros_like(t) for t in T]  # summed spin poll counts
         spins = [0] * len(groups)
         # per-call constants of each elementwise family's leg slots:
@@ -1305,7 +1352,9 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
             T[gi] += nt
 
         def tally(gi, d):
-            if d is not None:
+            if isinstance(d, np.ndarray):
+                td_rows[gi] += d
+            elif d is not None:
                 c = td_cnt[gi]
                 c[d] = c.get(d, 0) + 1
 
@@ -1348,6 +1397,11 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
             """Port-wavefront pricing in the event engine's global message
             order; each sweep extends every port's priced prefix with the
             touches whose upstream legs have resolved arrivals."""
+            with span("lockstep.ordered"):
+                count("lockstep.ordered_msgs", len(issue))
+                return _price_ordered(fam, issue, E_msg, legs)
+
+        def _price_ordered(fam, issue, E_msg, legs):
             nonlocal g_q
             nb = fam.nb
             m = len(issue)
@@ -1365,6 +1419,8 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
                 tmsg = msg[tord]
                 tprt = legs["port"][tord]
                 thops = legs["hops"][tord]
+            # bytes of each touch, where messages differ in size
+            tnb = nb[tmsg] if isinstance(nb, np.ndarray) else None
             first = np.ones(L, bool)
             first[1:] = tmsg[1:] != tmsg[:-1]
             ready = np.full(L, np.nan)
@@ -1402,14 +1458,19 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
                     tl = tl[:cnt]
                     rdy = rdy[:cnt]
                     p = int(plist[pi])
-                    ser = nb / port_bw[p]
+                    if tnb is None:
+                        ser = nb / port_bw[p]
+                        port_byt[p] += cnt * nb
+                    else:
+                        b = tnb[tl]
+                        ser = b / port_bw[p]
+                        port_byt[p] += int(b.sum())
                     sts, bfin = _chain(port_busy[p], rdy, ser)
                     port_busy[p] = bfin
                     port_used[p] = True
                     fin = sts + ser
                     q = sts - rdy
                     port_cnt[p] += cnt
-                    port_byt[p] += cnt * nb
                     port_qd[p] = float(
                         np.cumsum(np.concatenate(([port_qd[p]], q)))[-1]
                     )
@@ -1479,13 +1540,19 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
             emitted[fam] = emitted.get(fam, 0) + 1
             legs_cls = np.bincount(legs["cls"], minlength=C)
             cls_msgs[:] += legs_cls
-            cls_bytes[:] += legs_cls * fam.nb
+            if isinstance(fam.nb, np.ndarray):
+                # integer sums below 2**53: the float bincount is exact
+                cls_bytes[:] += np.bincount(
+                    legs["cls"], weights=fam.nb[legs["msg"]], minlength=C
+                ).astype(np.int64)
+            else:
+                cls_bytes[:] += legs_cls * fam.nb
 
         for ins in plan.instrs:
             tag = ins[0]
             if tag == "p":
                 _, gi, dur, td, fam, uid, _k = ins
-                if dur:
+                if isinstance(dur, np.ndarray) or dur:
                     T[gi] += dur
                 if fam is not None:
                     if fam.kind == "fanout_all":
@@ -1545,12 +1612,9 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
             for td, c in td_cnt[gi].items():
                 for j in range(6):
                     d[j] += c * td[j]
-            a_nfr[devs] += d[0] * tot
-            a_rb[devs] += d[1] * tot
-            a_lw[devs] += d[2] * tot
-            a_wb[devs] += d[3] * tot
-            a_xo[devs] += d[4] * tot
-            a_xob[devs] += d[5] * tot
+            rows = (td_rows[gi] + np.array(d, np.int64)) * tot
+            for j, a in enumerate((a_nfr, a_rb, a_lw, a_wb, a_xo, a_xob)):
+                a[devs] += rows[:, j]
         for fam, c in emitted.items():
             dw = fam.dw if include_dw and fam.dw > 0 else 0
             regs = 1 + dw
@@ -1578,7 +1642,7 @@ def run_tiered(cluster, plan: _TieredPlan, breakdown: Dict[str, float]):
                     np.add.at(a_marks, fam.dst, c * dw)
             seq_add += c * fam.m * regs
             g_msgs += c * fam.m
-            g_bytes += c * fam.m * fam.nb
+            g_bytes += c * fam.nb_sum
             if fam.cls_legs is not None:
                 cls_msgs += c * fam.cls_legs
                 cls_bytes += c * fam.cls_legs * fam.nb

@@ -287,8 +287,12 @@ class AffineRun:
 @dataclass(frozen=True)
 class EmitRun:
     """``count`` :class:`EmitOp`\\ s whose dst/slot advance affinely with the
-    member index ``j`` (shared payload/marker/coalesce fields) — the per-peer
-    fan-out of an incast phase as one descriptor."""
+    member index ``j`` (shared marker/coalesce fields) — the per-peer
+    fan-out of an incast phase as one descriptor.
+
+    ``payload_bytes`` is one size for every member, or a tuple of ``count``
+    sizes, member ``j``'s first (a skewed all-to-all's per-destination
+    payloads)."""
 
     count: int
     dst0: int
@@ -297,13 +301,24 @@ class EmitRun:
     slot_stride: int = 0
     data: int = 1
     size: int = 8
-    payload_bytes: int = 0
+    payload_bytes: Union[int, Tuple[int, ...]] = 0
     data_writes: int = 0
     coalesce: str = "last"
 
     def __post_init__(self) -> None:
         if self.count < 0:
             raise ValueError("EmitRun.count must be >= 0")
+        if isinstance(self.payload_bytes, tuple):
+            if len(self.payload_bytes) != self.count:
+                raise ValueError(
+                    "EmitRun.payload_bytes must give one size per member"
+                )
+            if any(p < 0 for p in self.payload_bytes):
+                raise ValueError("EmitRun payloads must be non-negative")
+
+    def payload_at(self, j: int) -> int:
+        p = self.payload_bytes
+        return p[j] if isinstance(p, tuple) else p
 
     def expand(self) -> Tuple[EmitOp, ...]:
         return tuple(
@@ -312,7 +327,7 @@ class EmitRun:
                 slot=self.slot0 + j * self.slot_stride,
                 data=self.data,
                 size=self.size,
-                payload_bytes=self.payload_bytes,
+                payload_bytes=self.payload_at(j),
                 data_writes=self.data_writes,
                 coalesce=self.coalesce,
             )

@@ -15,11 +15,14 @@ Importing this package registers every built-in with the scenario registry
 * ``hierarchical_allreduce`` — closed-loop cross-tier collective: intra-node
   ring reduce-scatter (ICI), leader ring all-reduce over the DCI uplinks,
   intra-node broadcast.
+* ``moe_ep``         — one expert-parallel MoE layer under skewed routing:
+  per-pair dispatch, per-rank expert time, the reverse combine.
 """
 
 from .all_to_all import AllToAllScenario
 from .gemv_allreduce import GemvAllReduceScenario
 from .hierarchical_allreduce import HierarchicalAllReduceScenario
+from .moe_ep import MoEExpertParallelScenario
 from .pipeline_p2p import PipelineP2PScenario
 from .ring_allreduce import RingAllReduceScenario
 
@@ -27,6 +30,7 @@ __all__ = [
     "AllToAllScenario",
     "GemvAllReduceScenario",
     "HierarchicalAllReduceScenario",
+    "MoEExpertParallelScenario",
     "PipelineP2PScenario",
     "RingAllReduceScenario",
 ]
